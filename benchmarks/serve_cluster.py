@@ -37,9 +37,10 @@ import jax
 import numpy as np
 
 from _emit import emit
-from repro.config import ServeConfig, TrainConfig, get_config
+from repro.config import ServeConfig, get_config
 from repro.serve import PagedEngine, QueueFull, ServeCluster, TenantSpec
-from repro.train.steps import init_train_state
+from repro.models.transformer import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -225,6 +226,7 @@ def run_qos(cfg, params, seed: int, *, slots: int, seq_len: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--replicas", type=int, default=4)
@@ -243,8 +245,7 @@ def main() -> None:
         args.reps = 1
 
     cfg = get_config("repro-tiny")
-    state = init_train_state(jax.random.PRNGKey(0), cfg, TrainConfig())
-    params = state["params"]
+    params = init_params(jax.random.PRNGKey(0), cfg)
     trace = make_session_trace(cfg.vocab_size, args.requests, args.seed)
 
     scaling = run_scaling(cfg, params, trace, replicas_hi=args.replicas,

@@ -31,9 +31,10 @@ from typing import List
 import jax
 import numpy as np
 
-from repro.config import ServeConfig, TrainConfig, get_config
+from repro.config import ServeConfig, get_config
 from repro.serve.engine import ContinuousEngine, FixedBatchEngine, QueueFull
-from repro.train.steps import init_train_state
+from repro.models.transformer import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 from _emit import emit
 
@@ -104,6 +105,7 @@ def run_continuous(eng: ContinuousEngine, trace: List[TraceItem]):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -117,14 +119,14 @@ def main() -> None:
         args.reps = 1
 
     cfg = get_config("repro-tiny")
-    state = init_train_state(jax.random.PRNGKey(0), cfg, TrainConfig())
+    params = init_params(jax.random.PRNGKey(0), cfg)
     scfg = ServeConfig(max_batch=args.max_batch, max_seq_len=128,
                        max_queue=4 * args.requests,
                        prefill_buckets=(8, 16))
     trace = make_trace(cfg.vocab_size, args.requests, args.seed)
 
-    fixed = FixedBatchEngine(cfg, state["params"], scfg)
-    cont = ContinuousEngine(cfg, state["params"], scfg)
+    fixed = FixedBatchEngine(cfg, params, scfg)
+    cont = ContinuousEngine(cfg, params, scfg)
     # compile warmup: every (length, batch) shape each engine will see in
     # the replay, including the ragged final chunk of each length group
     counts = {}

@@ -41,10 +41,11 @@ from typing import Dict, List
 import jax
 import numpy as np
 
-from repro.config import ServeConfig, TrainConfig, get_config
+from repro.config import ServeConfig, get_config
 from repro.core.endpoint import BlobEndpoint, EndpointRegistry
 from repro.serve.engine import DisaggregatedEngine, PagedEngine, QueueFull
-from repro.train.steps import init_train_state
+from repro.models.transformer import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 from _emit import emit
 
@@ -110,6 +111,7 @@ def outputs_of(eng, rids) -> Dict[int, List[int]]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--slots", type=int, default=4)
@@ -132,7 +134,7 @@ def main() -> None:
         args.reps = 1
 
     cfg = get_config("repro-tiny")
-    state = init_train_state(jax.random.PRNGKey(0), cfg, TrainConfig())
+    params = init_params(jax.random.PRNGKey(0), cfg)
     B, C, pg = args.slots, args.max_seq_len, args.page_size
     trace = make_long_prompt_trace(cfg.vocab_size, args.requests, args.seed)
 
@@ -144,12 +146,12 @@ def main() -> None:
     base = dict(max_batch=B, max_seq_len=C, page_size=pg,
                 num_pages=num_pages, max_queue=4 * args.requests,
                 prefill_buckets=(8, 16, 32, 64))
-    single = PagedEngine(cfg, state["params"], ServeConfig(**base))
+    single = PagedEngine(cfg, params, ServeConfig(**base))
 
     tmp = tempfile.TemporaryDirectory(prefix="kv-handoff-")
     peers = EndpointRegistry.local_peers(tmp.name, 2).peers()
     disagg = DisaggregatedEngine(
-        cfg, state["params"],
+        cfg, params,
         ServeConfig(**base, engine_mode="disaggregated",
                     disagg_route=args.route),
         handoff_endpoints=[BlobEndpoint(p) for p in peers])
@@ -205,7 +207,7 @@ def main() -> None:
         tmp8 = tempfile.TemporaryDirectory(prefix="kv-handoff8-")
         peers8 = EndpointRegistry.local_peers(tmp8.name, 2).peers()
         disagg8 = DisaggregatedEngine(
-            cfg, state["params"],
+            cfg, params,
             ServeConfig(**base, engine_mode="disaggregated",
                         disagg_route=args.route, kv_quant="int8"),
             handoff_endpoints=[BlobEndpoint(p) for p in peers8])

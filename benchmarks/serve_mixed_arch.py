@@ -24,9 +24,10 @@ import jax
 import numpy as np
 
 from _emit import emit
-from repro.config import ServeConfig, TrainConfig, get_config
+from repro.config import ServeConfig, get_config
 from repro.serve import ContinuousEngine, QueueFull, ServeCluster
-from repro.train.steps import init_train_state
+from repro.models.transformer import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def make_trace(vocab: int, n: int, seed: int, *, lens=(8, 16, 24),
@@ -66,6 +67,7 @@ def replay(clu: ServeCluster, traces: Dict[str, list]):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=24,
                     help="requests per model group")
@@ -84,10 +86,8 @@ def main() -> None:
 
     t_cfg = get_config("repro-tiny")
     r_cfg = get_config("rwkv6-3b").reduced()
-    t_params = init_train_state(jax.random.PRNGKey(0), t_cfg,
-                                TrainConfig())["params"]
-    r_params = init_train_state(jax.random.PRNGKey(1), r_cfg,
-                                TrainConfig())["params"]
+    t_params = init_params(jax.random.PRNGKey(0), t_cfg)
+    r_params = init_params(jax.random.PRNGKey(1), r_cfg)
 
     scfg = ServeConfig(
         engine_mode="cluster", num_replicas=args.replicas,

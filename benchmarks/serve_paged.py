@@ -35,9 +35,10 @@ from typing import Dict, List
 import jax
 import numpy as np
 
-from repro.config import ServeConfig, TrainConfig, get_config
+from repro.config import ServeConfig, get_config
 from repro.serve.engine import ContinuousEngine, PagedEngine, QueueFull
-from repro.train.steps import init_train_state
+from repro.models.transformer import init_params
+from repro.launch.compile_cache import enable_compile_cache
 
 from _emit import emit
 
@@ -106,6 +107,7 @@ def outputs_of(eng, rids) -> Dict[int, List[int]]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=30)
     ap.add_argument("--dense-slots", type=int, default=4)
@@ -124,17 +126,17 @@ def main() -> None:
         args.reps = 1
 
     cfg = get_config("repro-tiny")
-    state = init_train_state(jax.random.PRNGKey(0), cfg, TrainConfig())
+    params = init_params(jax.random.PRNGKey(0), cfg)
     C, pg, B = args.max_seq_len, args.page_size, args.dense_slots
     trace = make_shared_prefix_trace(cfg.vocab_size, args.requests, args.seed)
 
     # Fixed cache memory: the dense engine's B x C cache entries buy the
     # paged engine a pool of B*C/pg pages — on which it runs 2B slots,
     # because residency follows live tokens (~prefix sharing included).
-    dense = ContinuousEngine(cfg, state["params"], ServeConfig(
+    dense = ContinuousEngine(cfg, params, ServeConfig(
         max_batch=B, max_seq_len=C, max_queue=4 * args.requests,
         prefill_buckets=(8, 16, 32, 64)))
-    paged = PagedEngine(cfg, state["params"], ServeConfig(
+    paged = PagedEngine(cfg, params, ServeConfig(
         max_batch=2 * B, max_seq_len=C, max_queue=4 * args.requests,
         prefill_buckets=(8, 16, 32, 64),
         page_size=pg, num_pages=B * C // pg + 1, cold_pages=256))
@@ -149,7 +151,7 @@ def main() -> None:
         # same memory buys ~3.55x pages — run 2x the f32-paged slots on it.
         N = cfg.d_model // cfg.num_heads
         q_pages = (B * C // pg) * (4 * N) // (N + 4)
-        paged8 = PagedEngine(cfg, state["params"], ServeConfig(
+        paged8 = PagedEngine(cfg, params, ServeConfig(
             max_batch=4 * B, max_seq_len=C, max_queue=4 * args.requests,
             prefill_buckets=(8, 16, 32, 64), kv_quant="int8",
             page_size=pg, num_pages=q_pages + 1, cold_pages=256))

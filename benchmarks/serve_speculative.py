@@ -41,6 +41,7 @@ import numpy as np
 from repro.config import ServeConfig, get_config
 from repro.models.transformer import init_params
 from repro.serve import PagedEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 from _emit import emit
 
@@ -92,6 +93,7 @@ def replay(eng, trace):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=8)

@@ -14,10 +14,10 @@ import time
 import jax
 import numpy as np
 
-from repro.config import ServeConfig, TrainConfig, get_config
+from repro.config import ServeConfig, get_config
+from repro.models.transformer import init_params
 from repro.serve.engine import ContinuousEngine, QueueFull
 from repro.serve.sampler import SamplingParams
-from repro.train.steps import init_train_state
 
 
 def main():
@@ -32,8 +32,8 @@ def main():
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, TrainConfig())
-    eng = ContinuousEngine(cfg, state["params"],
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousEngine(cfg, params,
                            ServeConfig(max_batch=args.max_batch))
     sampling = SamplingParams(temperature=0.8, top_k=40)
 

@@ -22,9 +22,9 @@ the device, and jitted program builders must stay deterministic.
   impure calls bake a trace-time value into the compiled program and
   silently desync replicas that compiled at different moments.
 * **KERNEL_GUARD** — every ``kernels/<name>/ops.py`` must expose a
-  ``supported(...)`` gate containing a ``%`` divisibility check, so block
-  shapes that don't tile the Pallas grid fall back to the reference path
-  instead of mis-launching.
+  ``supported(...)`` gate containing a ``%`` divisibility check or a ``<=``
+  size bound, so block shapes that don't tile the Pallas grid, or don't fit
+  its VMEM, fall back to the reference path instead of mis-launching.
 
 Reachability is a deliberately simple call graph: hot roots are matched by
 *name* (so a new backend's ``admit`` is hot the day it is written), edges
@@ -248,14 +248,17 @@ def _check_kernels(sources: List[SourceFile]) -> List[Finding]:
                 "kernel ops module has no supported() gate: callers "
                 "cannot check Pallas block-shape constraints before launch"))
             continue
-        has_mod = any(
-            isinstance(n, ast.BinOp) and isinstance(n.op, ast.Mod)
+        has_check = any(
+            (isinstance(n, ast.BinOp) and isinstance(n.op, ast.Mod))
+            or (isinstance(n, ast.Compare)
+                and any(isinstance(op, ast.LtE) for op in n.ops))
             for n in ast.walk(supported))
-        if not has_mod:
+        if not has_check:
             findings.append(Finding(
                 KERNEL_GUARD, src.path, supported.lineno,
                 "supported",
-                "supported() has no '%' block-shape divisibility check"))
+                "supported() has no '%' block-shape divisibility check "
+                "or '<=' size bound"))
     return findings
 
 

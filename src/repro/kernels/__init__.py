@@ -7,6 +7,14 @@ oracle).  ``register_all`` populates the core.accelerators registry.
 from __future__ import annotations
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: on the CPU backend
+    only.  Any other backend compiles them for real, so an accelerator that
+    failed to come up cannot turn into a silently interpreted run."""
+    import jax
+    return jax.default_backend() == "cpu"
+
+
 def register_all() -> None:
     from repro.core.accelerators import AcceleratedOp, register_op
     from repro.kernels.flash_attention import ops as fa

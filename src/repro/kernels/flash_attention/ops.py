@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_bhsn
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def supported(q, k, v, *, q_pos=None, k_pos=None, causal=True, window=0,
@@ -45,7 +42,7 @@ def flash_attention(q, k, v, *, q_pos=None, k_pos=None, causal=True,
     vh = v.transpose(0, 2, 1, 3).reshape(B * J, T, N)
     out = flash_attention_bhsn(
         qh, kh, vh, group=G, causal=causal, window=window, scale=1.0,
-        bq=bq, bk=bk, interpret=_interpret())
+        bq=bq, bk=bk, interpret=interpret_mode())
     return out.reshape(B, J * G, S, N).transpose(0, 2, 1, 3) \
               .reshape(B, S, J, G, N)
 

@@ -5,12 +5,9 @@ import functools
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.rglru.kernel import linear_scan_pallas
 from repro.kernels.rglru.ref import linear_scan_ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def supported(a, b=None, *, bs: int = 128, bw: int = 128) -> bool:
@@ -25,4 +22,4 @@ def linear_scan(a: jax.Array, b: jax.Array, *, bs: int = 128,
         bw //= 2
     while a.shape[1] % bs:
         bs //= 2
-    return linear_scan_pallas(a, b, bs=bs, bw=bw, interpret=_interpret())
+    return linear_scan_pallas(a, b, bs=bs, bw=bw, interpret=interpret_mode())

@@ -5,12 +5,9 @@ import functools
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def supported(x, scale=None, *, eps: float = 1e-6, br: int = 128) -> bool:
@@ -26,5 +23,5 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     br = min(br, x2.shape[0])
-    out = rmsnorm_pallas(x2, scale, eps=eps, br=br, interpret=_interpret())
+    out = rmsnorm_pallas(x2, scale, eps=eps, br=br, interpret=interpret_mode())
     return out.reshape(shape)

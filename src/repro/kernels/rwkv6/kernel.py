@@ -32,10 +32,17 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
     kc = k_ref[0].astype(jnp.float32)
     vc = v_ref[0].astype(jnp.float32)
     wc = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)        # (1, N) block -> (N,)
+    u = u_ref[0].astype(jnp.float32)        # (1, 1, N) block -> (1, N)
 
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     logw = jnp.log(jnp.maximum(wc, 1e-30))
-    cum = jnp.cumsum(logw, axis=0)
+    # Prefix sum over the chunk as a lower-triangular matmul: Mosaic has no
+    # cumsum lowering.
+    cum = jax.lax.dot_general(jnp.where(si <= ti, 1.0, 0.0), logw,
+                              (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)
     winc = jnp.exp(cum)                     # decay incl. t
     wexc = jnp.exp(cum - logw)              # decay up to t-1
 
@@ -48,8 +55,6 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
     # intra-chunk, strictly-lower-triangular pairs
     A = jax.lax.dot_general(rw, kw, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (c, c)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     A = jnp.where(si < ti, A, 0.0)
     y = y + jax.lax.dot_general(A, vc, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -58,9 +63,9 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
     y = y + diag * vc
     o_ref[0] = y.astype(o_ref.dtype)
     # state update
-    wlast = winc[-1]                        # (N,)
-    kdec = kw * wlast[None, :]
-    S_new = wlast[:, None] * S + jax.lax.dot_general(
+    wlast = winc[chunk - 1:chunk]           # (1, N)
+    kdec = kw * wlast
+    S_new = wlast.T * S + jax.lax.dot_general(
         kdec, vc, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     s_scr[...] = S_new
@@ -68,8 +73,12 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
 
 def rwkv6_pallas(r, k, v, w, u, *, chunk: int = 64,
                  interpret: bool = False) -> jax.Array:
-    """r,k,v,w: (BH, T, N) f32; u: (BH?, ...) -> per-head (H, N) expanded to
-    (BH, N) by the wrapper. Returns y (BH, T, N)."""
+    """r,k,v,w: (BH, T, N) f32; u: per-head (H, N) expanded to (BH, N) by the
+    wrapper. Returns y (BH, T, N).
+
+    ``u`` rides as (BH, 1, N) with (1, 1, N) blocks: Mosaic wants a block's
+    last two dims (8, 128)-aligned or equal to the array's, which a (1, N)
+    block of a (BH, N) array is not."""
     BH, T, N = r.shape
     chunk = min(chunk, T)
     nc = T // chunk
@@ -78,16 +87,16 @@ def rwkv6_pallas(r, k, v, w, u, *, chunk: int = 64,
         return (bh, ic, 0)
 
     def umap(bh, ic):
-        return (bh, 0)
+        return (bh, 0, 0)
 
     spec = pl.BlockSpec((1, chunk, N), imap)
     return pl.pallas_call(
         functools.partial(_rwkv6_kernel, chunk=chunk),
         grid=(BH, nc),
         in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec((1, N), umap)],
+                  pl.BlockSpec((1, 1, N), umap)],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((BH, T, N), r.dtype),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(BH, 1, N))

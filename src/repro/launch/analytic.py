@@ -37,15 +37,6 @@ def _dtype_bytes(cfg: ModelConfig) -> int:
     return 2 if cfg.dtype == "bfloat16" else 4
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` compat: newer jax returns a dict, older
-    versions a one-element list of dicts.  Always returns a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 # ----------------------------------------------------------------------------
 # Forward matmul flops
 # ----------------------------------------------------------------------------

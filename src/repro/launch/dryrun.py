@@ -146,7 +146,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             "temp_bytes": int(getattr(ma, "temp_size_in_bytes", 0)),
             "alias_bytes": int(getattr(ma, "alias_size_in_bytes", 0)),
         }
-        ca = analytic.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         # NOTE: the compiled module is the per-device SPMD program, so
         # cost_analysis flops/bytes are PER DEVICE (verified empirically);
         # corrections are computed per-device via sharding degrees.

@@ -9,8 +9,6 @@ from __future__ import annotations
 import math
 from typing import Optional, Tuple
 
-import numpy as np
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """(16,16) data x model per pod; (2,16,16) pod x data x model multi-pod."""
@@ -31,7 +29,7 @@ def make_local_mesh(data: int = 1, model: int = 1):
 
 def _mesh(shape, axes):
     import jax
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType
     n = math.prod(shape)
     devs = jax.devices()
     if len(devs) < n:
@@ -39,9 +37,5 @@ def _mesh(shape, axes):
             f"mesh {shape} needs {n} devices, have {len(devs)} — the dry-run "
             "entrypoint must set XLA_FLAGS=--xla_force_host_platform_device_"
             "count before importing jax")
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes, devices=devs[:n],
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):    # older jax: no AxisType / kwarg
-        return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))

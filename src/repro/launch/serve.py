@@ -21,10 +21,11 @@ import time
 import jax
 import numpy as np
 
-from repro.config import EngineMode, ServeConfig, TrainConfig, get_config
+from repro.config import EngineMode, ServeConfig, get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.transformer import init_params
 from repro.serve import QueueFull, ServeCluster, make_engine
 from repro.serve.sampler import SamplingParams
-from repro.train.steps import init_train_state
 
 
 def main() -> None:
@@ -64,12 +65,13 @@ def main() -> None:
                          "'self-int8' (int8-quantized target), or a "
                          "registry arch name with the same vocab")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mode = args.engine_mode
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    state = init_train_state(jax.random.PRNGKey(args.seed), cfg, TrainConfig())
+    params = init_params(jax.random.PRNGKey(args.seed), cfg)
     scfg = ServeConfig(max_batch=args.max_batch,
                        temperature=args.temperature, seed=args.seed,
                        page_size=args.page_size, num_pages=args.num_pages,
@@ -85,7 +87,7 @@ def main() -> None:
         ap.error("--engine-mode fixed is the equal-length benchmark "
                  "baseline (no admission plane); use "
                  "benchmarks/serve_continuous.py to exercise it")
-    eng = make_engine(cfg, state["params"], scfg)
+    eng = make_engine(cfg, params, scfg)
     is_cluster = isinstance(eng, ServeCluster)
     sampling = SamplingParams.from_config(scfg)
 

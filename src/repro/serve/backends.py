@@ -60,6 +60,13 @@ from repro.serve.kvpool import (
 from repro.serve.scheduler import Request
 
 
+def place_beside(tree: Any, params: Any) -> Any:
+    """``tree`` on the device(s) that hold ``params``: a cluster replica
+    keeps its decode state on its own device, next to its weights."""
+    leaf = jax.tree.leaves(params)[0]
+    return jax.device_put(tree, getattr(leaf, "sharding", None))
+
+
 def make_backend(cfg: ModelConfig, scfg: ServeConfig) -> "CacheBackend":
     """Pick the decode-state discipline for an arch: block-table KV paging
     when the arch supports it, the snapshot pool otherwise.  This is the
@@ -237,9 +244,11 @@ class PagedKVBackend(CacheBackend):
         if eng._draft is not None:
             self._verify_prog = programs.paged_verify_program(
                 self.cfg, eng.policy, self.scfg.draft_k)
-        eng.states = init_paged_decode_state(self.cfg, self.pool.num_pages,
-                                             self.page_size,
-                                             kv_quant=self.scfg.kv_quant)
+        eng.states = place_beside(
+            init_paged_decode_state(self.cfg, self.pool.num_pages,
+                                    self.page_size,
+                                    kv_quant=self.scfg.kv_quant),
+            eng.params)
 
     # -- tiered-memory plane ---------------------------------------------------
     def _spill(self, page: int, chain: bytes) -> None:
@@ -699,8 +708,10 @@ class SnapshotBackend(CacheBackend):
         if eng._draft is not None:
             self._verify_prog = programs.snapshot_verify_program(
                 self.cfg, eng.policy, self.scfg.draft_k)
-        eng.states = init_decode_state(self.cfg, self.scfg.max_batch,
-                                       capacity=self.scfg.max_seq_len)
+        eng.states = place_beside(
+            init_decode_state(self.cfg, self.scfg.max_batch,
+                              capacity=self.scfg.max_seq_len),
+            eng.params)
 
     # -- tiered-memory plane ---------------------------------------------------
     def _spill(self, key: bytes, length: int, state: Any) -> None:

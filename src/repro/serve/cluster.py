@@ -6,9 +6,9 @@ This module generalizes it to the ROADMAP's "millions of users" shape:
 
   * **N decode replicas per model group**, each a full ``PagedEngine``
     (own slot table, own cache backend — page pool + prefix index for
-    paged archs, snapshot pool for recurrent/SWA archs) — on this container
-    they share one process and one device, on a pod each is its own
-    endpoint; the compiled-program cache (``serve.programs``) means N
+    paged archs, snapshot pool for recurrent/SWA archs), all in one process,
+    replica i on device i (round-robin when replicas outnumber devices);
+    the compiled-program cache (``serve.programs``) means N
     replicas cost one set of traces.  ``extra_models`` registers additional
     (config, params) groups, so one cluster serves transformer and
     recurrent traffic concurrently; requests name their group via
@@ -195,16 +195,26 @@ class ServeCluster:
             self.models[name] = (mcfg, mparams)
         self.replicas: List[PagedEngine] = []
         self._model_of: List[str] = []
+        # Replica i lives on device i (round-robin past the device count):
+        # its weights are placed there and its engine allocates its decode
+        # state beside them.  Handoffs cross as host bytes, so no array op
+        # ever spans two devices.
+        devices = jax.devices()
         for name, (mcfg, mparams) in self.models.items():
             for _ in range(scfg.num_replicas):
                 i = len(self.replicas)
+                dev = devices[i % len(devices)]
                 # An explicit drafter override is built against the default
                 # group's weights; extra groups resolve their own from
                 # scfg.draft_model (e.g. a layer-skip of their own params).
+                rep_drafter = None
+                if drafter is not None and name == "default":
+                    rep_drafter = (drafter[0], jax.device_put(drafter[1], dev))
                 self.replicas.append(PagedEngine(
-                    mcfg, mparams, rep_scfg, policy, executor=self.executor,
+                    mcfg, jax.device_put(mparams, dev), rep_scfg, policy,
+                    executor=self.executor,
                     handoff_endpoints=handoff_eps, handoff_ns=f"r{i}/",
-                    drafter=(drafter if name == "default" else None)))
+                    drafter=rep_drafter))
                 self._model_of.append(name)
         n_total = len(self.replicas)
         self.alive = [True] * n_total

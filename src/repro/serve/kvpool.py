@@ -374,13 +374,15 @@ def pack_handoff(h: Any) -> bytes:
 
 # Exactly the types a packed handoff is built from: the two handoff
 # dataclasses and the numpy array/scalar/dtype reconstruction machinery
-# (page blobs are numpy trees, sampling params are numpy scalars).  The
+# (page blobs are numpy trees, sampling params are numpy scalars), plus the
+# bfloat16 element type that bf16 models' pages carry.  The
 # ``numpy._core`` aliases cover numpy >= 2 pickles read under either layout.
 _HANDOFF_SAFE = {
     ("repro.serve.kvpool", "KVHandoff"),
     ("repro.serve.backends", "SnapshotHandoff"),
     ("numpy", "ndarray"),
     ("numpy", "dtype"),
+    ("ml_dtypes", "bfloat16"),
     ("numpy.core.multiarray", "_reconstruct"),
     ("numpy._core.multiarray", "_reconstruct"),
     ("numpy.core.multiarray", "scalar"),

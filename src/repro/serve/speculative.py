@@ -44,6 +44,7 @@ from repro.config.run import ServeConfig
 from repro.models.transformer import (
     ExecPolicy, init_decode_state, init_params, supports_paging)
 from repro.serve import programs
+from repro.serve.backends import place_beside
 
 
 def make_draft_config(cfg: ModelConfig, n_layers: int) -> ModelConfig:
@@ -142,8 +143,9 @@ class DraftPlane:
             cfg, policy, scfg.max_seq_len)
         self._propose_prog = programs.draft_propose_program(
             cfg, policy, scfg.draft_k)
-        self.states = init_decode_state(cfg, scfg.max_batch,
-                                        capacity=scfg.max_seq_len)
+        self.states = place_beside(
+            init_decode_state(cfg, scfg.max_batch, capacity=scfg.max_seq_len),
+            params)
 
     def admit(self, slot: int, prompt: np.ndarray, bucket: int) -> None:
         """Prefill ``prompt`` into the drafter's state at ``slot`` (one

@@ -43,7 +43,7 @@ def flops_for(pol, reps):
     with mesh:
         comp = jax.jit(fn, in_shardings=(s_sh, b_sh),
                        donate_argnums=0).lower(state, batch).compile()
-    raw = analytic.cost_analysis_dict(comp).get("flops")
+    raw = comp.cost_analysis().get("flops")
     corr = analytic.scan_corrections(cfg, spec, pol.q_chunk or 0,
                                      pol.kv_chunk or 0, mesh_shape, reps)
     return raw + corr.flops

@@ -155,3 +155,23 @@ def test_remesh_plan():
     assert plan.ok
     bad = remesh_plan(cfg, old, MeshConfig(data=7, model=16), global_batch=256)
     assert not bad.ok
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_path(monkeypatch,
+                                                         tmp_path):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR, when set,
+    is left to JAX and nothing is set in code; otherwise the cache goes to
+    the fixed .jax_cache at the root of the checkout."""
+    from repro.launch.compile_cache import enable_compile_cache
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        path = enable_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -116,6 +116,17 @@ def test_rmsnorm_sweep(shape, dtype, rng):
                                  - ref.astype(jnp.float32)))) < TOL[dtype]
 
 
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", False)])
+def test_interpret_mode_only_on_cpu(backend, interpret, monkeypatch):
+    """Kernels fall back to the Pallas interpreter on the CPU backend only;
+    any other backend compiles them for real (a TPU that failed to come up
+    must not turn into a silently interpreted run)."""
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert interpret_mode() is interpret
+
+
 # ----------------------------------------------------------------------------
 # accelerator registry (G1 dispatch)
 # ----------------------------------------------------------------------------

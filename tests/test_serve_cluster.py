@@ -79,6 +79,52 @@ def test_cluster_matches_single_engine(tiny_engine_parts):
     clu.close()
 
 
+PLACEMENT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import dataclasses
+import jax, numpy as np
+from repro.config import ServeConfig, get_config
+from repro.models.transformer import init_params
+from repro.serve import PagedEngine, ServeCluster
+cfg = get_config("repro-tiny")
+params = init_params(jax.random.PRNGKey(0), cfg)
+scfg = ServeConfig(max_batch=2, max_seq_len=96, prefill_buckets=(8, 16),
+                   page_size=8, engine_mode="cluster", num_replicas=4)
+rng = np.random.default_rng(0)
+prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+           for n in (5, 9, 12, 7, 16, 3, 11, 8)]
+ref = PagedEngine(cfg, params, dataclasses.replace(scfg, engine_mode="paged"))
+clu = ServeCluster(cfg, params, scfg)
+a, b = ref.generate(prompts, 6), clu.generate(prompts, 6)
+assert all(a[i].output == b[i] for i in range(len(prompts)))
+devs = jax.devices()
+for i, rep in enumerate(clu.replicas):
+    for tree in (rep.params, rep.states):
+        assert {l.devices().pop() for l in jax.tree.leaves(tree)} == \
+            {devs[i]}, (i, tree is rep.params)
+assert all(clu.alive) and clu.stats()["qos"]["replica_deaths"] == 0
+print("PLACED")
+"""
+
+
+def test_cluster_places_each_replica_on_its_own_device():
+    """With four devices, replica i keeps its weights and decode state on
+    device i, prompts prefilled on device 0 cross as host bytes, and the
+    tokens still equal one PagedEngine's.  Subprocess: the virtual device
+    count must be set before JAX starts."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    out = subprocess.run([sys.executable, "-c", PLACEMENT_SCRIPT],
+                         capture_output=True, text=True, timeout=300,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "PLACED" in out.stdout
+
+
 def test_prefix_affinity_routes_to_page_owner(tiny_engine_parts):
     """A prompt whose prefix pages live on replica 1 must route there, even
     though the tie-break would otherwise pick replica 0."""

@@ -151,6 +151,25 @@ def test_disaggregated_int8_matches_single_int8_engine(tiny_engine_parts):
     dis.close()
 
 
+def test_bf16_handoff_roundtrip(tiny_engine_parts):
+    """bf16 models (every published config) hand off bf16 pages: the
+    restricted unpickler must accept their element type and carry the
+    pages bit-exactly."""
+    import dataclasses
+    cfg, params = tiny_engine_parts
+    cfg16 = dataclasses.replace(cfg, dtype="bfloat16")
+    params16 = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
+    rng = np.random.default_rng(3)
+    worker = PrefillWorker(cfg16, params16, _scfg())
+    h = worker.prefill_to_handoff(5, _prompt(rng, cfg, 13), 4,
+                                  SamplingParams())
+    assert jax.tree.leaves(h.page_blobs)[0].dtype == jnp.bfloat16
+    h2 = unpack_handoff(pack_handoff(h))
+    for b1, b2 in zip(h.page_blobs, h2.page_blobs):
+        _leaves_equal(b1, b2)
+    worker.close()
+
+
 def test_unpack_handoff_rejects_malformed_blobs(tiny_engine_parts):
     """A truncated stream, a non-pickle payload, and a pickle referencing a
     global outside the handoff allowlist must all surface as the same
