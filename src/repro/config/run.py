@@ -106,7 +106,6 @@ class ServeConfig:
     eos_id: int = -1                 # -1 -> no EOS eviction
     prefill_buckets: Tuple[int, ...] = (16, 32, 64, 128, 256, 512)
     result_shards: int = 4           # ShardedStore endpoints for results
-    stats_every: int = 64            # engine-stats snapshot period (steps)
     # Paged KV-cache (PagedEngine): fixed-size pages + block tables instead
     # of a dense per-slot cache; memory scales with live tokens.
     page_size: int = 16              # tokens per physical KV page

@@ -10,6 +10,9 @@ blocks.  Properties the paper's doctrine requires:
   * **Bounded queue + backpressure policy** — an overloaded sidecar must not
     grow unbounded (the cost model's G2-overload case); policies: "block"
     (checkpoints — correctness), "drop_oldest" (metrics — lossy ok).
+    Under "block" a full queue does stall the caller: each such submit is
+    counted in ``stats()`` and spanned as ``sidecar.blocked`` in a
+    profiler trace.
   * **Failure isolation** — a sidecar task failure (e.g. a flaky replication
     peer) is recorded and retried; it never propagates into the step loop.
     This is the fault-tolerance contract: background-plane failures are
@@ -25,6 +28,7 @@ import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.locks import make_condition, make_lock
 
@@ -69,18 +73,29 @@ class BackgroundExecutor:
         self.backpressure = backpressure
         self.max_retries = max_retries
         self._q: "queue.Queue[_Task]" = queue.Queue(maxsize=max_inflight)
-        # _lock guards history/drop accounting; _cv guards in-flight counts.
-        # They are never nested — keep it that way, or the lock-order
-        # sanitizer will record an edge between them.
+        # _lock guards the outcome and back-pressure totals; _cv guards
+        # in-flight counts.  They are never nested — keep it that way, or the
+        # lock-order sanitizer will record an edge between them.
         self._lock = make_lock("BackgroundExecutor._lock")
-        self._history: List[TaskRecord] = []    # guarded-by: _lock
         self._stop = threading.Event()
+        # Running totals, not a record per task: a serving process submits a
+        # task per spilled page and per finished request, for its lifetime.
+        self._completed = 0                     # guarded-by: _lock
+        self._failed = 0                        # guarded-by: _lock
         self._dropped = 0                       # guarded-by: _lock
+        self._wait_s = 0.0                      # guarded-by: _lock
+        self._run_s = 0.0                       # guarded-by: _lock
+        self._errors: List[str] = []            # guarded-by: _lock
+        # Back-pressure: submits that found the queue full under "block",
+        # and the perf_counter seconds their callers spent blocked.
+        self._blocked_submits = 0               # guarded-by: _lock
+        self._blocked_s = 0.0                   # guarded-by: _lock
         # In-flight accounting for drain(): counts accepted-but-unfinished
         # tasks under a condition variable (queue.Queue.unfinished_tasks is
         # undocumented, and join() has no timeout).
         self._cv = make_condition("BackgroundExecutor._cv")
         self._inflight = 0                      # guarded-by: _cv
+        self._submitted = 0                     # guarded-by: _cv
         self._threads = [
             threading.Thread(target=self._worker, daemon=True,
                              name=f"sidecar-{i}")
@@ -100,6 +115,7 @@ class BackgroundExecutor:
                     pass
         task = _Task(name, fn, arrays, self.max_retries)
         with self._cv:
+            self._submitted += 1
             rejected = self._stop.is_set()
             if not rejected:
                 self._inflight += 1   # count before enqueue: no drain races
@@ -111,7 +127,7 @@ class BackgroundExecutor:
             task.done.set()
             with self._lock:
                 self._dropped += 1
-                self._history.append(task.record)
+                self._account(task.record)
             return task
         while True:
             try:
@@ -119,14 +135,20 @@ class BackgroundExecutor:
                 return task
             except queue.Full:
                 if self.backpressure == "block":
-                    self._q.put(task)
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("sidecar.blocked"):
+                        self._q.put(task)
+                    blocked = time.perf_counter() - t0
+                    with self._lock:
+                        self._blocked_submits += 1
+                        self._blocked_s += blocked
                     return task
                 if self.backpressure == "reject":
                     task.record.error = "rejected: queue full"
                     task.done.set()
                     with self._lock:
                         self._dropped += 1
-                        self._history.append(task.record)
+                        self._account(task.record)
                     self._finish_one()
                     return task
                 # drop_oldest
@@ -136,10 +158,21 @@ class BackgroundExecutor:
                     old.done.set()
                     with self._lock:
                         self._dropped += 1
-                        self._history.append(old.record)
+                        self._account(old.record)
                     self._finish_one()
                 except queue.Empty:
                     pass
+
+    def _account(self, rec: TaskRecord) -> None:  # requires: _lock
+        """Fold one finished (or dropped) task into the running totals."""
+        if rec.error is None:
+            self._completed += 1
+            self._wait_s += rec.wait_s
+            self._run_s += rec.run_s
+        else:
+            self._failed += 1
+            if len(self._errors) < 8:
+                self._errors.append(rec.error)
 
     def _finish_one(self) -> None:
         with self._cv:
@@ -173,9 +206,9 @@ class BackgroundExecutor:
             task.record.finished_at = time.time()
             task.done.set()
             with self._lock:
-                self._history.append(task.record)
-            self._finish_one()        # after history: drain()==True implies
-            self._q.task_done()       # records are visible
+                self._account(task.record)
+            self._finish_one()        # after the totals: drain()==True
+            self._q.task_done()       # implies the outcome is counted
 
     # -- introspection / lifecycle ----------------------------------------------
     def drain(self, timeout: float = 30.0) -> bool:
@@ -186,19 +219,27 @@ class BackgroundExecutor:
             return self._cv.wait_for(lambda: self._inflight == 0, timeout)
 
     def stats(self) -> Dict[str, Any]:
+        """Outcome totals (``failed`` counts dropped tasks too, as each
+        carries an error), mean queue wait and run time of the completed
+        tasks, the first 8 errors, and back-pressure: ``submitted`` calls,
+        ``blocked_submits`` that waited for room and the ``blocked_s``
+        seconds their callers spent waiting."""
+        with self._cv:
+            submitted = self._submitted
         with self._lock:
-            hist = list(self._history)
-            dropped = self._dropped
-        ok = [r for r in hist if r.error is None]
-        failed = [r for r in hist if r.error is not None]
-        return {
-            "completed": len(ok),
-            "failed": len(failed),
-            "dropped": dropped,
-            "mean_wait_s": sum(r.wait_s for r in ok) / len(ok) if ok else 0.0,
-            "mean_run_s": sum(r.run_s for r in ok) / len(ok) if ok else 0.0,
-            "errors": [r.error for r in failed][:8],
-        }
+            ok = self._completed
+            s = {
+                "completed": ok,
+                "failed": self._failed,
+                "dropped": self._dropped,
+                "mean_wait_s": self._wait_s / ok if ok else 0.0,
+                "mean_run_s": self._run_s / ok if ok else 0.0,
+                "errors": list(self._errors),
+                "blocked_submits": self._blocked_submits,
+                "blocked_s": self._blocked_s,
+            }
+        s["submitted"] = submitted
+        return s
 
     def shutdown(self, drain: bool = True):
         """Stop the workers.  Idempotent: a second call is a no-op sweep.
@@ -224,5 +265,5 @@ class BackgroundExecutor:
             task.done.set()
             with self._lock:
                 self._dropped += 1
-                self._history.append(task.record)
+                self._account(task.record)
             self._finish_one()

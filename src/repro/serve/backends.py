@@ -47,6 +47,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config.model import ModelConfig
 from repro.config.run import ServeConfig
@@ -283,7 +284,8 @@ class PagedKVBackend(CacheBackend):
         blob = self.cold.take(chain)
         if blob is None:
             return None
-        got = self.pool.alloc(1, evict_cb=self._spill)
+        with TraceAnnotation("kv.alloc"):
+            got = self.pool.alloc(1, evict_cb=self._spill)
         if got is None:
             self.cold.put(chain, blob)          # no room: stay cold
             return None
@@ -300,21 +302,21 @@ class PagedKVBackend(CacheBackend):
         """Longest chain of *full* prompt pages already resident (hot hit)
         or spilled (cold fault-in).  Always leaves >= 1 token to prefill so
         the admit program has a real last-token logit to sample from."""
-        pg = self.page_size
-        limit = (len(req.prompt) - 1) // pg
+        limit = (len(req.prompt) - 1) // self.page_size
         pages: List[int] = []
-        for chain in chains[:limit]:
-            # Atomic hit + pin: a separate lookup()/ref() pair would let a
-            # concurrent alloc() evict the page in between and hand it to
-            # another slot (the late ref would pin foreign KV).
-            page = self.pool.lookup_and_ref(chain)
-            if page is not None:
+        with TraceAnnotation("kv.match"):
+            for chain in chains[:limit]:
+                # Atomic hit + pin: a separate lookup()/ref() pair would let
+                # a concurrent alloc() evict the page in between and hand it
+                # to another slot (the late ref would pin foreign KV).
+                page = self.pool.lookup_and_ref(chain)
+                if page is not None:
+                    pages.append(page)
+                    continue
+                page = self._fault_in(chain)    # alloc() already ref'd it
+                if page is None:
+                    break
                 pages.append(page)
-                continue
-            page = self._fault_in(chain)        # alloc() already ref'd it
-            if page is None:
-                break
-            pages.append(page)
         return pages
 
     def prepare_probe(self, prompt: np.ndarray):
@@ -358,7 +360,8 @@ class PagedKVBackend(CacheBackend):
         rolled back so decode can free pages in the meantime."""
         hit_pages = self._match_prefix(req, chains)
         n_hit = len(hit_pages)
-        new_pages = self.pool.alloc(need - n_hit, evict_cb=self._spill)
+        with TraceAnnotation("kv.alloc"):
+            new_pages = self.pool.alloc(need - n_hit, evict_cb=self._spill)
         if new_pages is None:                   # pool exhausted by live slots:
             for p in hit_pages:                 # defer; decode will free pages
                 self.pool.unref(p)
@@ -420,7 +423,8 @@ class PagedKVBackend(CacheBackend):
             eng.params, eng.states, batch, eng._key, eng._mirrors)
         if self.scfg.prefix_cache:
             self._register_prefix(req, chains, pages, n_hit)
-        return int(tok[0])
+        with TraceAnnotation("serve.admit.wait"):
+            return int(tok[0])
 
     # -- handoff ---------------------------------------------------------------
     def export_handoff(self, req: Request, rid: int, max_new_tokens: int,
@@ -509,7 +513,8 @@ class PagedKVBackend(CacheBackend):
         eng.states, toks_dev, eng._key, eng._mirrors = self._decode_prog(
             eng.params, eng.states, eng._key, eng._mirrors,
             jnp.asarray(self._table))
-        return np.asarray(toks_dev)
+        with TraceAnnotation("serve.decode.wait"):
+            return np.asarray(toks_dev)
 
     def verify_step(self, drafts: jax.Array,
                     caps: jax.Array) -> Tuple[np.ndarray, np.ndarray]:
@@ -525,7 +530,8 @@ class PagedKVBackend(CacheBackend):
         eng.states, out, acc, eng._key, eng._mirrors = self._verify_prog(
             eng.params, eng.states, eng._key, eng._mirrors,
             jnp.asarray(self._table), drafts, caps)
-        out, acc = np.asarray(out), np.asarray(acc)
+        with TraceAnnotation("serve.decode.wait"):
+            out, acc = np.asarray(out), np.asarray(acc)
         self._count_rollback(acc, int(drafts.shape[1]))
         return out, acc
 
@@ -857,7 +863,8 @@ class SnapshotBackend(CacheBackend):
         if register:        # capture the spliced slot as a fresh snapshot
             solo = self._read_slot_prog(eng.states,
                                         jnp.asarray(slot, jnp.int32))
-        return int(tok[0]), solo
+        with TraceAnnotation("serve.admit.wait"):
+            return int(tok[0]), solo
 
     def _admit_resume(self, req: Request, donor: Any,
                       hit_len: int) -> Tuple[int, Any]:
@@ -886,7 +893,8 @@ class SnapshotBackend(CacheBackend):
         eng.states, solo, tok, eng._key, eng._mirrors = self._resume_prog(
             eng.params, eng.states, donor, batch,
             jnp.asarray(slot, jnp.int32), eng._key, eng._mirrors)
-        return int(tok[0]), solo
+        with TraceAnnotation("serve.admit.wait"):
+            return int(tok[0]), solo
 
     # -- handoff ---------------------------------------------------------------
     def export_handoff(self, req: Request, rid: int, max_new_tokens: int,
@@ -949,7 +957,8 @@ class SnapshotBackend(CacheBackend):
         eng = self.engine
         eng.states, toks_dev, eng._key, eng._mirrors = self._decode_prog(
             eng.params, eng.states, eng._key, eng._mirrors)
-        return np.asarray(toks_dev)
+        with TraceAnnotation("serve.decode.wait"):
+            return np.asarray(toks_dev)
 
     def verify_step(self, drafts: jax.Array,
                     caps: jax.Array) -> Tuple[np.ndarray, np.ndarray]:
@@ -962,7 +971,8 @@ class SnapshotBackend(CacheBackend):
         eng = self.engine
         eng.states, out, acc, eng._key, eng._mirrors = self._verify_prog(
             eng.params, eng.states, eng._key, eng._mirrors, drafts, caps)
-        out, acc = np.asarray(out), np.asarray(acc)
+        with TraceAnnotation("serve.decode.wait"):
+            out, acc = np.asarray(out), np.asarray(acc)
         self._count_rollback(acc, int(drafts.shape[1]))
         return out, acc
 

@@ -12,14 +12,21 @@ The split follows the paper's doctrine directly:
     freed slots are recycled, and queued requests are prefilled solo and
     spliced into the running batch — new arrivals join mid-decode instead of
     waiting for a full batch to drain.
-  * **Bookkeeping (sidecar, G2)** — latency records, token accounting and
-    periodic engine stats go through ``BackgroundExecutor``; the step loop
-    never blocks on them.
+  * **Bookkeeping (sidecar, G2)** — latency records and spilled-page
+    staging go through ``BackgroundExecutor``; the step loop blocks on them
+    only when the sidecar's queue is full (counted in ``stats()["sidecar"]``).
   * **Results (G3)** — completed generations land in a ``ShardedStore``
     hash-sharded over peer endpoints, the paper's Redis-slot scheme.
 
 ``FixedBatchEngine`` keeps the old drain-the-whole-batch behavior as the
 benchmark baseline (``benchmarks/serve_continuous.py``).
+
+Spans: the loop marks its phases with ``jax.profiler.TraceAnnotation``, which
+records only while a profiler trace runs, on the device trace's clock.
+``serve.step`` holds ``serve.admit`` (one per admission attempt, with its
+``rid``) and ``serve.decode``; each holds a ``*.wait`` span around its
+blocking device->host readback.  ``serve.submit`` marks an enqueue, and the
+paged backend adds ``kv.match`` and ``kv.alloc`` inside an admission.
 """
 from __future__ import annotations
 
@@ -31,6 +38,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config.model import ModelConfig
 from repro.config.run import ServeConfig
@@ -100,11 +108,10 @@ class ContinuousEngine:
         # stats() stays O(1) on the decode loop
         self._shard_balance = self.store.balance()
         # One lock covers everything mutated by the engine loop and read from
-        # other threads (records, stats_log, step/token counters): stats()
-        # and result() may legally race the loop thread.
+        # other threads (records, step/token counters): stats() and result()
+        # may legally race the loop thread.
         self._lock = make_lock("ContinuousEngine._lock")
         self.records: List[Dict[str, Any]] = []        # guarded-by: _lock
-        self.stats_log: List[Dict[str, Any]] = []      # guarded-by: _lock
 
         self._rid = itertools.count()
         self._requests: Dict[int, Request] = {}        # guarded-by: _admission
@@ -185,7 +192,7 @@ class ContinuousEngine:
                       stop=normalize_stop(stop), on_token=on_token)
         # Atomic against _fail_pending's teardown so a request can never
         # slip into the queue after close() already failed everything.
-        with self._admission:
+        with TraceAnnotation("serve.submit", rid=req.rid), self._admission:
             if self._closed.is_set():
                 raise RuntimeError("engine is closed; no new submissions")
             self.scheduler.push(req)      # raises QueueFull at capacity
@@ -226,10 +233,13 @@ class ContinuousEngine:
         admitted = 0
         while self.slots.free_count() and not self.scheduler.empty():
             req = self.scheduler.pop()
-            tok0 = self._admit_one(req)
+            began = time.time()
+            with TraceAnnotation("serve.admit", rid=req.rid):
+                tok0 = self._admit_one(req)
             if tok0 is None:            # resource shortage (paged engine):
                 self.scheduler.push_front(req)   # retry after evictions free
                 break                            # pages on later steps
+            req.admitted_at = began
             sp = req.sampling
             slot = req.slot
             req.first_token_at = time.time()
@@ -271,7 +281,8 @@ class ContinuousEngine:
         self.states, tok, self._key, self._mirrors = self._admit_prog(
             self.params, self.states, batch,
             jnp.asarray(slot, jnp.int32), self._key, self._mirrors)
-        return int(tok[0])
+        with TraceAnnotation("serve.admit.wait"):
+            return int(tok[0])
 
     def _deliver(self, req: Request, start: int) -> None:
         """Stream ``req.output[start:]`` to the request's ``on_token``
@@ -315,15 +326,25 @@ class ContinuousEngine:
         """Run the fused decode program; returns the (B,) sampled tokens."""
         self.states, toks_dev, self._key, self._mirrors = self._decode_prog(
             self.params, self.states, self._key, self._mirrors)
-        return np.asarray(toks_dev)
+        with TraceAnnotation("serve.decode.wait"):
+            return np.asarray(toks_dev)
 
     def _decode_once(self) -> bool:
         """One batched decode step over all slots + per-slot evictions."""
-        if self._draft is not None:
-            return self._decode_spec_once()
         active = self.slots.active()
         if not active:
             return False
+        with TraceAnnotation("serve.decode"):
+            if self._draft is not None:
+                self._decode_spec_once(active)
+            else:
+                self._decode_plain(active)
+            with self._lock:
+                self._steps += 1
+        return True
+
+    def _decode_plain(self, active: List[Request]) -> None:
+        """One token per active slot; finished slots are released."""
         toks = self._decode_device()
         for req in active:
             slot = req.slot
@@ -339,26 +360,22 @@ class ContinuousEngine:
                 # in the output (callers strip them if they want clean text).
                 self._release_slot(slot)
                 self._finish(req)
-        self._after_step()
-        return True
 
     def _verify_device(self, drafts: jax.Array, caps: jax.Array):
         """Run the fused verify program; returns the host (B, k+1) emitted
         chunk and (B,) accept lengths."""
         self.states, out, acc, self._key, self._mirrors = self._verify_prog(
             self.params, self.states, self._key, self._mirrors, drafts, caps)
-        return np.asarray(out), np.asarray(acc)
+        with TraceAnnotation("serve.decode.wait"):
+            return np.asarray(out), np.asarray(acc)
 
-    def _decode_spec_once(self) -> bool:
+    def _decode_spec_once(self, active: List[Request]) -> None:
         """One speculative macro step: the drafter proposes k tokens per
         slot, the target verifies all k+1 positions in one batched forward,
         and each slot commits its accepted prefix — with the same per-token
         termination semantics as sequential decode (EOS, token budget, or a
         stop sequence completing *inside* the chunk truncate mid-chunk, at
         the earliest trigger)."""
-        active = self.slots.active()
-        if not active:
-            return False
         k = self._draft.k
         caps = jnp.asarray(self._caps)
         drafts = self._draft_propose(caps)
@@ -397,20 +414,6 @@ class ContinuousEngine:
             self._spec_steps += 1
             self._spec_proposed += proposed
             self._spec_accepted += accepted
-        self._after_step()
-        return True
-
-    def _after_step(self) -> None:
-        with self._lock:
-            self._steps += 1
-            steps = self._steps
-        if self.scfg.stats_every and steps % self.scfg.stats_every == 0:
-            snap = self.stats()
-            self.executor.submit("serve.stats", self._append_stats, snap)
-
-    def _append_stats(self, snap: Dict[str, Any]) -> None:
-        with self._lock:
-            self.stats_log.append(snap)
 
     def step(self) -> bool:
         """Admit + one decode step.  Returns False once fully idle.
@@ -419,7 +422,7 @@ class ContinuousEngine:
         request: it is recorded (so ``result()`` surfaces it instead of
         reporting the request as forever "still decoding") and every
         pending request gets a terminal error record before re-raising."""
-        with self._lifecycle:
+        with TraceAnnotation("serve.step"), self._lifecycle:
             if self._closed.is_set():
                 return False
             try:
@@ -443,6 +446,7 @@ class ContinuousEngine:
             "rid": req.rid,
             "tokens": list(req.output),
             "prompt_len": int(len(req.prompt)),
+            "queue_s": req.admitted_at - req.submitted_at,
             "ttft_s": req.first_token_at - req.submitted_at,
             "e2e_s": done_at - req.submitted_at,
         }
@@ -539,6 +543,7 @@ class ContinuousEngine:
         }
         if cb_errors:
             s["callback_errors"] = cb_errors
+        s["sidecar"] = self.executor.stats()
         if self._draft is not None:
             msteps, prop, acc = spec
             s["speculative"] = {

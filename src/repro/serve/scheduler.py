@@ -88,6 +88,9 @@ class Request:
     sampling: SamplingParams = SamplingParams()
     frontend_embeds: Optional[np.ndarray] = None   # (1, M, F)
     submitted_at: float = dataclasses.field(default_factory=time.time)
+    # Start of the admission attempt that succeeded: the queue wait is
+    # admitted_at - submitted_at (0.0 until admitted).
+    admitted_at: float = 0.0
     first_token_at: float = 0.0
     finished_at: float = 0.0
     slot: int = -1

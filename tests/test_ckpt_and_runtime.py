@@ -118,6 +118,47 @@ def test_executor_failure_isolation():
     ex.shutdown(drain=False)
 
 
+def test_executor_stats_are_totals_of_every_task_record():
+    """stats() keeps its keys and meanings as running totals: completed and
+    failed (dropped tasks included) counts, mean wait and run of the
+    completed ones, the first 8 errors in the order they were recorded."""
+    ex = BackgroundExecutor(num_threads=1, max_inflight=1,
+                            backpressure="reject", max_retries=0)
+    gate, running = threading.Event(), threading.Event()
+
+    def blocker():
+        running.set()
+        gate.wait(5.0)
+
+    def boom():
+        raise ValueError("boom")
+
+    tasks = [ex.submit("blocker", blocker)]
+    assert running.wait(5.0)
+    tasks.append(ex.submit("boom", boom))        # takes the one queue place
+    tasks += [ex.submit(f"r{i}", lambda: None) for i in range(9)]  # rejected
+    gate.set()
+    assert ex.drain(timeout=5.0)
+    for i in range(3):                           # one at a time: accepted
+        tasks.append(ex.submit(f"ok{i}", lambda i=i: i))
+        assert tasks[-1].done.wait(5.0)
+    assert ex.drain(timeout=5.0)                 # totals counted
+    recs = [t.record for t in tasks]
+    ok = [r for r in recs if r.error is None]
+    stats = ex.stats()
+    assert {k: stats[k] for k in ("completed", "failed", "dropped",
+                                  "errors")} == {
+        "completed": 4, "failed": 10, "dropped": 9,
+        "errors": ["rejected: queue full"] * 8}
+    assert stats["mean_wait_s"] == pytest.approx(
+        sum(r.wait_s for r in ok) / len(ok))
+    assert stats["mean_run_s"] == pytest.approx(
+        sum(r.run_s for r in ok) / len(ok))
+    assert tasks[1].record.error == "ValueError: boom"
+    assert stats["submitted"] == len(tasks) and stats["blocked_submits"] == 0
+    ex.shutdown(drain=False)
+
+
 def test_executor_backpressure_drop_oldest():
     ex = BackgroundExecutor(num_threads=1, max_inflight=2,
                             backpressure="drop_oldest")

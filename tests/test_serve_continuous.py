@@ -249,7 +249,7 @@ def test_stats_and_results_race_free_with_engine_loop(tiny_engine_parts):
     concurrent reader never tears a read or crashes (regression: unsynced
     reads of _steps/_tokens_out/records mutated by the loop thread)."""
     cfg, params = tiny_engine_parts
-    eng = _engine(cfg, params, stats_every=1)
+    eng = _engine(cfg, params)
     rng = np.random.default_rng(9)
     rids = [eng.submit(_prompt(rng, cfg, 6 + i % 4), 12) for i in range(8)]
     errors = []
@@ -278,7 +278,6 @@ def test_stats_and_results_race_free_with_engine_loop(tiny_engine_parts):
     assert not errors, errors
     final = eng.stats()
     assert final["tokens_out"] >= 8              # all requests produced tokens
-    assert len(eng.stats_log) > 0                # sidecar snapshots landed
     eng.close()
 
 
