@@ -10,7 +10,9 @@ seed, in this one process: a chip serves one process at a time.
   A  Default policy.  8 greedy requests, prompts of 16-400 tokens (five
      prefill buckets), 32 new tokens each, on a paged engine.  The trace runs
      twice on fresh engines: the first run compiles, the second is timed and
-     must repeat the first's tokens.
+     must repeat the first's tokens.  The engine's decode and admit programs
+     (``jit_step``, ``jit_admit``) must hold no copy or transpose of whole
+     layers of the K/V page pool: they update it in place.
   B  The same trace with ``ExecPolicy(use_kernel=True)``.  The compiled
      decode program must hold the Pallas paged-attention kernel
      (``tpu_custom_call``), so a ``supported()`` refusal cannot quietly send
@@ -48,6 +50,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.config import ServeConfig, get_config  # noqa: E402
 from repro.kernels.paged_attention import ops as pa  # noqa: E402
+from repro.launch.hlo_analysis import pool_relayouts  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models.attention import kv_quantize  # noqa: E402
 from repro.models.common import dtype_of  # noqa: E402
@@ -106,14 +109,47 @@ def _prompts(cfg, lens, seed):
     return [rng.integers(0, cfg.vocab_size, n).astype(np.int32) for n in lens]
 
 
+def compiled_step(eng, cfg, policy):
+    """The paged engine's decode program, compiled again from the engine's
+    live arguments (the persistent compilation cache makes that a
+    lookup)."""
+    return programs.paged_decode_program(cfg, policy).lower(
+        eng.params, eng.states, eng._key, eng._mirrors,
+        jnp.asarray(eng.backend._table)).compile()
+
+
+def compiled_admit(eng, cfg, policy, bucket: int):
+    """The paged engine's admit program for one suffix bucket, compiled
+    from the engine's live state and an admission batch of that shape."""
+    M = eng.backend.pages_per_seq
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    batch = {"tokens": jnp.zeros((1, bucket), jnp.int32),
+             "positions": jnp.arange(bucket, dtype=jnp.int32)[None],
+             "length": i32(bucket), "hit_len": i32(0),
+             "table": jnp.zeros(M, jnp.int32),
+             "assign": jnp.zeros(M, jnp.int32), "slot": i32(0),
+             "temp": jnp.asarray(0.0, jnp.float32), "top_k": i32(0),
+             "top_p": jnp.asarray(1.0, jnp.float32)}
+    return programs.paged_admit_program(
+        cfg, policy, eng.scfg.max_seq_len).lower(
+            eng.params, eng.states, batch, eng._key, eng._mirrors).compile()
+
+
 def decode_has_kernel(eng, cfg, policy) -> bool:
     """Whether the paged engine's compiled decode program holds a Pallas
-    kernel (compiled again from the engine's live arguments; the persistent
-    compilation cache makes that a lookup)."""
-    lowered = programs.paged_decode_program(cfg, policy).lower(
-        eng.params, eng.states, eng._key, eng._mirrors,
-        jnp.asarray(eng.backend._table))
-    return "tpu_custom_call" in lowered.compile().as_text()
+    kernel."""
+    return "tpu_custom_call" in compiled_step(eng, cfg, policy).as_text()
+
+
+def pool_copies(eng, cfg, policy) -> dict:
+    """Per program: (pool-sized copies and transposes, scratch bytes)."""
+    pool = [leaf for leaf in jax.tree.leaves(eng.states) if leaf.ndim == 3]
+    out = {}
+    for name, exe in (("jit_step", compiled_step(eng, cfg, policy)),
+                      ("jit_admit", compiled_admit(eng, cfg, policy, 16))):
+        out[name] = (pool_relayouts(exe.as_text(), pool),
+                     exe.memory_analysis().temp_size_in_bytes)
+    return out
 
 
 def phase_a(cfg, params, scfg, dev):
@@ -122,11 +158,19 @@ def phase_a(cfg, params, scfg, dev):
     eng.close()
     del eng
     res, wall_s, eng = serve_trace(cfg, params, scfg, prompts, ExecPolicy())
+    copies = pool_copies(eng, cfg, ExecPolicy())
     eng.close()
     del eng
     toks = [r["tokens"] for r in res]
     if toks != [r["tokens"] for r in warm]:
         _fail("phase A: a second run of the same trace gave other tokens")
+    report = " ".join(f"{name}_pool_copies={len(found)} {name}_temp_bytes="
+                      f"{temp}" for name, (found, temp) in copies.items())
+    print(f"phase A: {report}", flush=True)
+    for name, (found, _) in copies.items():
+        if found:
+            _fail(f"phase A: {name} copies whole layers of the page pool:\n"
+                  + "\n".join(line[:200] for line in found))
     print(f"phase A: ok requests={len(prompts)} prompt_lens={PROMPT_LENS} "
           f"new_tokens={NEW_TOKENS} warmup_s={warm_s} (compiles included) "
           f"wall_s={wall_s} peak_bytes_in_use={_peak_bytes(dev)}", flush=True)

@@ -110,7 +110,8 @@ class ServeConfig:
     # of a dense per-slot cache; memory scales with live tokens.
     page_size: int = 16              # tokens per physical KV page
     num_pages: int = 0               # pool size; 0 -> full residency for
-    #                                  every slot (max_batch * pages_per_seq)
+    #                                  every slot (max_batch * pages_per_seq
+    #                                  + scratch, rounded up to 64 pages)
     prefix_cache: bool = True        # hash-keyed prefix page sharing (CoW)
     kv_quant: str = "none"           # "none" | "int8": quantized KV pages
     #                                  (int8 values + per-entry f32 scales;

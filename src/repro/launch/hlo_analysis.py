@@ -15,6 +15,9 @@ Ops inside while-loop bodies are multiplied by the loop trip count, which is
 recovered from the loop-condition's comparison constant (scan lowers to a
 while with a counter compared against a literal).  This matters because XLA's
 ``cost_analysis`` counts a while body exactly once.
+
+``pool_relayouts`` finds copies and transposes of whole layers of a serve
+state's page pool: a program that updates its pool in place has none.
 """
 from __future__ import annotations
 
@@ -166,3 +169,36 @@ def collective_stats(hlo: str) -> CollectiveStats:
             ops.append((kind, wire, g))
             count += mult
     return CollectiveStats(sum(by_kind.values()), dict(by_kind), count, ops)
+
+
+_HLO_TYPE = {"bfloat16": "bf16", "float16": "f16", "float32": "f32",
+             "int8": "s8"}
+_MOVE_RE = re.compile(
+    r"=\s+\(?([a-z0-9]+)\[([0-9,]*)\][^=]*?\s(copy|copy-start|transpose)\(")
+
+
+def pool_relayouts(hlo: str, leaves) -> List[str]:
+    """The copies, copy-starts and transposes in ``hlo`` that hold whole
+    layers of a stacked pool leaf ``(layers, rows, width)`` (anything with
+    ``dtype`` and ``shape``): the leaf's element type, a whole multiple of
+    one layer's elements, and a dim equal to its row count or row width.
+    Returns their HLO lines."""
+    found = []
+    for line in hlo.splitlines():
+        m = _MOVE_RE.search(line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        n = 1
+        for d in dims:
+            n *= d
+        for leaf in leaves:
+            per_layer = 1
+            for d in leaf.shape[1:]:
+                per_layer *= d
+            if (m.group(1) == _HLO_TYPE.get(str(leaf.dtype))
+                    and n >= per_layer and n % per_layer == 0
+                    and set(leaf.shape[1:]) & set(dims)):
+                found.append(line.strip())
+                break
+    return found

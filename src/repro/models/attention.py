@@ -167,6 +167,7 @@ def self_attention(
     causal: bool = True,
     cache: Optional[dict] = None,
     page_table: Optional[jax.Array] = None,   # (B, M) int32 — paged decode
+    layer=None,                   # index into stacked page pools, or None
     q_chunk: int = 0,
     kv_chunk: int = 0,
     use_kernel: bool = False,
@@ -176,8 +177,8 @@ def self_attention(
     if cache is not None and "kp" in cache:
         # Paged decode (S == 1): K/V live in a shared physical page pool and
         # are addressed through the block table instead of a per-slot buffer.
-        cache = paged_cache_write(cache, k, v, positions, page_table)
-        out = paged_attend(q, cache, positions, page_table,
+        cache = paged_cache_write(cache, k, v, positions, page_table, layer)
+        out = paged_attend(q, cache, positions, page_table, layer,
                            cap=cfg.attn_logit_softcap, use_kernel=use_kernel)
         o = jnp.einsum("bsjgn,jgnd->bsd", out,
                        params["wo"].reshape(cfg.num_kv_heads, -1,
@@ -268,6 +269,23 @@ def kv_dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale[..., None]
 
 
+# A pool leaf stores whole pages along its rows.  Value rows hold one page
+# (``page*J*N`` wide); a page's ``page*J`` f32 scales are too few for a row
+# of their own (80 at smollm widths would pad to 128 lanes), so a scale row
+# holds this many pages side by side.
+_SCALE_LEAVES = ("ksc", "vsc")
+_SCALE_PAGES_PER_ROW = 8
+# A pool of a multiple of this many pages fills whole (8, 128) tiles of
+# rows in every leaf (scale rows too: 64 pages are 8 rows).  Given a ragged
+# count, the TPU compiler stores a stacked leaf with the layer axis inside
+# the page axis, and every program relayouts the pool in and out.
+POOL_PAGE_MULTIPLE = 64
+
+
+def _pages_per_row(key: str) -> int:
+    return _SCALE_PAGES_PER_ROW if key in _SCALE_LEAVES else 1
+
+
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                      dtype, kv_quant: str = "none") -> dict:
     """Physical K/V page pool shared by every slot (one per layer).  There is
@@ -275,8 +293,17 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     row's logical view is live iff ``t < length`` — because pages are written
     densely from position 0 and never ring-wrap.
 
+    A page is a row: ``kp``/``vp`` are ``(P, page*J*N)``, entry ``o`` of
+    page ``p`` at columns ``[o*J*N, (o+1)*J*N)``; ``P`` should be a
+    multiple of ``POOL_PAGE_MULTIPLE``.  With the page axis major
+    and a wide minor axis, TPU's default layout tiles the pool without
+    padding, and every page mover (:func:`take_pages`, :func:`put_pages`,
+    the decode write) reads and writes it in that layout, by windows of
+    rows, so no program relayouts a pool.
+
     ``kv_quant="int8"`` stores pages as int8 with per-(entry, head) f32
-    scales in sibling ``ksc``/``vsc`` leaves (shape ``(P, page, J)``), so a
+    scales in sibling ``ksc``/``vsc`` leaves of ``ceil(P/8)`` rows of 8
+    pages each, so a
     page costs ``J*(N + 4)`` bytes per entry instead of ``4*J*N`` — ~3.5x
     more pages per byte at N=32.  The scale leaves ride the same generic
     page movers (``read_page``/``write_page``) as the values, so spill,
@@ -286,88 +313,208 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                          f"got {kv_quant!r}")
     j, n = cfg.num_kv_heads, cfg.head_dim
     if kv_quant == "int8":
+        rows = -(-num_pages // _SCALE_PAGES_PER_ROW)
+        width = _SCALE_PAGES_PER_ROW * page_size * j
         return {
-            "kp": jnp.zeros((num_pages, page_size, j, n), jnp.int8),
-            "vp": jnp.zeros((num_pages, page_size, j, n), jnp.int8),
-            "ksc": jnp.zeros((num_pages, page_size, j), jnp.float32),
-            "vsc": jnp.zeros((num_pages, page_size, j), jnp.float32),
+            "kp": jnp.zeros((num_pages, page_size * j * n), jnp.int8),
+            "vp": jnp.zeros((num_pages, page_size * j * n), jnp.int8),
+            "ksc": jnp.zeros((rows, width), jnp.float32),
+            "vsc": jnp.zeros((rows, width), jnp.float32),
         }
     return {
-        "kp": jnp.zeros((num_pages, page_size, j, n), dtype),
-        "vp": jnp.zeros((num_pages, page_size, j, n), dtype),
+        "kp": jnp.zeros((num_pages, page_size * j * n), dtype),
+        "vp": jnp.zeros((num_pages, page_size * j * n), dtype),
     }
 
 
+def _page_starts(key: str, leaf: jax.Array, pages, layer=None,
+                 offset=0) -> jax.Array:
+    """Start indices, ``(..., leaf.ndim)``, of the windows of pool leaf
+    ``key`` that begin ``offset`` values into each page of ``pages``.  A
+    stacked leaf (``(L, rows, width)``) takes ``layer``, or with ``layer``
+    None gets a leading axis over all its layers; an unstacked leaf
+    (``(rows, width)``) ignores ``layer``."""
+    per_row = _pages_per_row(key)
+    pages = jnp.asarray(pages, jnp.int32)
+    width = leaf.shape[-1] // per_row
+    starts = [pages // per_row, (pages % per_row) * width + offset]
+    if leaf.ndim == 3:
+        if layer is None:
+            shape = (leaf.shape[0],) + (1,) * pages.ndim
+            starts = [jnp.arange(leaf.shape[0], dtype=jnp.int32)
+                      .reshape(shape)] + [a[None] for a in starts]
+        else:
+            starts = [jnp.asarray(layer, jnp.int32)] + starts
+    return jnp.stack(jnp.broadcast_arrays(*starts), axis=-1)
+
+
+def _read_windows(leaf: jax.Array, starts: jax.Array, size: int
+                  ) -> jax.Array:
+    dims = jax.lax.GatherDimensionNumbers(
+        offset_dims=(starts.ndim - 1,),
+        collapsed_slice_dims=tuple(range(leaf.ndim - 1)),
+        start_index_map=tuple(range(leaf.ndim)))
+    return jax.lax.gather(leaf, starts, dims,
+                          (1,) * (leaf.ndim - 1) + (size,),
+                          mode=jax.lax.GatherScatterMode.CLIP)
+
+
+def _write_windows(leaf: jax.Array, starts: jax.Array, new: jax.Array
+                   ) -> jax.Array:
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(new.ndim - 1,),
+        inserted_window_dims=tuple(range(leaf.ndim - 1)),
+        scatter_dims_to_operand_dims=tuple(range(leaf.ndim)))
+    return jax.lax.scatter(leaf, starts, new.astype(leaf.dtype), dims)
+
+
+def take_pages(key: str, leaf: jax.Array, pages, layer=None) -> jax.Array:
+    """Pages ``pages`` (any int shape) of pool leaf ``key``, each as its
+    flat ``page * entry`` values: ``pages.shape + (width,)``, with a leading
+    layer axis for a stacked leaf read at every layer (``layer`` None).
+    One gather of row windows — no slice of a layer's whole pool is built
+    first."""
+    width = leaf.shape[-1] // _pages_per_row(key)
+    return _read_windows(leaf, _page_starts(key, leaf, pages, layer), width)
+
+
+def _rows(leaf: jax.Array, pages, layer=None) -> tuple:
+    """Index of whole rows ``pages`` of a value leaf: in layer ``layer`` of
+    a stacked leaf, in all its layers (``layer`` None, a leading axis), or
+    in an unstacked leaf.  Gathers and scatters of whole rows run natively
+    on TPU; a scatter of part-row windows runs as one update per window."""
+    pages = jnp.asarray(pages, jnp.int32)
+    if leaf.ndim == 2:
+        return (pages,)
+    if layer is None:
+        layers = jnp.arange(leaf.shape[0], dtype=jnp.int32)
+        return (layers.reshape((-1,) + (1,) * pages.ndim), pages[None])
+    return (layer, pages)
+
+
+def put_pages(key: str, leaf: jax.Array, pages, new: jax.Array
+              ) -> jax.Array:
+    """Inverse of :func:`take_pages` at every layer, into the (donated)
+    leaf, touching only those pages.  Value pages are whole rows: one
+    scatter.  Scale pages share rows, so each page's window of every layer
+    is updated in turn (a window spanning the layers in one scatter makes
+    the compiler relayout the leaf).  Duplicate targets (unused logical
+    pages all aimed at the scratch page) are harmless."""
+    new = new.astype(leaf.dtype)
+    if key not in _SCALE_LEAVES:
+        return leaf.at[_rows(leaf, pages)].set(new)
+    width = leaf.shape[-1] // _SCALE_PAGES_PER_ROW
+    pages = jnp.asarray(pages, jnp.int32).reshape(-1)
+    lead = leaf.shape[:-2]
+    new = new.reshape(lead + (pages.shape[0], width))
+
+    def put(i, leaf):
+        p = pages[i]
+        start = (0,) * len(lead) + (p // _SCALE_PAGES_PER_ROW,
+                                    (p % _SCALE_PAGES_PER_ROW) * width)
+        return jax.lax.dynamic_update_slice(
+            leaf, jax.lax.dynamic_slice_in_dim(new, i, 1, len(lead)), start)
+    return jax.lax.fori_loop(0, pages.shape[0], put, leaf)
+
+
 def paged_cache_write(cache: dict, k: jax.Array, v: jax.Array,
-                      positions: jax.Array, table: jax.Array) -> dict:
+                      positions: jax.Array, table: jax.Array,
+                      layer=None) -> dict:
     """Decode/verify write: row ``b``'s token at position ``p`` lands in
     physical page ``table[b, p // page]`` at offset ``p % page``.  S > 1
-    (a speculative verify chunk) scatters all B*S entries in one shot; each
+    (a speculative verify chunk) writes the chunk's positions in order; each
     entry resolves its own page through the row's block table, so a chunk
-    may straddle a page boundary.
+    may straddle a page boundary.  ``layer`` picks the layer of a stacked
+    pool (``(L, P, row)`` leaves); the write updates only the new entries,
+    so a pool carried through the layer loop is updated in place.
 
     Rows whose slot was released have their table row pointed at the scratch
     page (0) by the admission plane, so their garbage writes never touch a
     live page; duplicate scratch indices in the scatter are harmless."""
-    B, S = k.shape[0], k.shape[1]
-    page = cache["kp"].shape[1]
+    B, S, J, N = k.shape
+    page = cache["kp"].shape[-1] // (J * N)
     M = table.shape[1]
     rows = jnp.arange(B)[:, None]                           # (B, 1)
     logical = jnp.minimum(positions // page, M - 1)         # clamp dead rows
-    phys = table[rows, logical].reshape(-1)                 # (B*S,)
-    off = (positions % page).reshape(-1)                    # (B*S,)
-    kf = k.reshape(B * S, *k.shape[2:])
-    vf = v.reshape(B * S, *v.shape[2:])
+    phys = table[rows, logical]                             # (B, S)
+    off = positions % page                                  # (B, S)
+    kf = k.reshape(B * S, J, N)
+    vf = v.reshape(B * S, J, N)
+
+    def put(key, new):
+        pool = cache[key]
+        new = new.reshape(B, S, -1)                         # one entry each
+        if key in _SCALE_LEAVES:
+            starts = _page_starts(key, pool, phys, layer,
+                                  off * new.shape[-1])
+            return _write_windows(pool, starts, new)
+        # Value pages are whole rows: read each row's page, set its entry,
+        # write the page back, one chunk position at a time.  A row's pages
+        # are its own, so only released rows (all on the scratch page)
+        # collide within a round.
+        entry = (jnp.arange(pool.shape[-1]) // new.shape[-1])[None]
+        tiled = jnp.tile(new, (1, 1, page)).astype(pool.dtype)
+        for s in range(S):
+            idx = _rows(pool, phys[:, s], layer)
+            pages = jnp.where(entry == off[:, s, None], tiled[:, s],
+                              pool[idx])
+            pool = pool.at[idx].set(pages)
+        return pool
     if "ksc" in cache:
         # Quantize-on-write: the new tokens' K/V rows land as int8 values
         # plus their per-(row, head) scales, so decode appends cost the same
         # bytes as prefilled pages and attention dequantizes uniformly.
         kq, ks = kv_quantize(kf)
         vq, vs = kv_quantize(vf)
-        return {
-            "kp": cache["kp"].at[phys, off].set(kq),
-            "vp": cache["vp"].at[phys, off].set(vq),
-            "ksc": cache["ksc"].at[phys, off].set(ks),
-            "vsc": cache["vsc"].at[phys, off].set(vs),
-        }
-    return {
-        "kp": cache["kp"].at[phys, off].set(kf.astype(cache["kp"].dtype)),
-        "vp": cache["vp"].at[phys, off].set(vf.astype(cache["vp"].dtype)),
-    }
+        return {"kp": put("kp", kq), "vp": put("vp", vq),
+                "ksc": put("ksc", ks), "vsc": put("vsc", vs)}
+    return {"kp": put("kp", kf), "vp": put("vp", vf)}
 
 
 def paged_attend(q: jax.Array, cache: dict, positions: jax.Array,
-                 table: jax.Array, *, cap: float = 0.0,
+                 table: jax.Array, layer=None, *, cap: float = 0.0,
                  use_kernel: bool = False) -> jax.Array:
     """Decode attention over the page pool.  q (B, S, J, G, N) pre-scaled;
     S == 1 is the ordinary decode step, S == k+1 a speculative verify chunk
     (each query position masks its own causal horizon, so stale entries
-    beyond a row's last write are invisible).
+    beyond a row's last write are invisible).  ``layer`` as in
+    :func:`paged_cache_write`.
 
     Kernel path (TPU, S == 1 only): the Pallas kernel DMAs K/V page-by-page
     through the block table — the quantized variant dequantizes inside the
-    kernel, so f32 pages are never materialized.  Oracle path: gather the
-    logical view (dequantizing if the pool carries scale leaves) and reuse
-    ``attend`` — bit-identical to the dense-cache decode for f32 pools."""
+    kernel, so f32 pages are never materialized.  It takes the
+    ``(P, page, J, N)`` view of one layer's pool, which this path rebuilds
+    at the call.  Oracle path: gather the logical view (dequantizing to the
+    query's dtype if the pool carries scale leaves) and reuse ``attend`` —
+    bit-identical to the dense-cache decode for f32 pools."""
     lengths = positions[:, -1] + 1                          # just wrote up to
     quant = "ksc" in cache
+    B, M = table.shape
+    J, N = q.shape[2], q.shape[4]
+    page = cache["kp"].shape[-1] // (J * N)
     if use_kernel and q.shape[1] == 1:
         from repro.kernels.paged_attention import ops as pa_ops
-        if pa_ops.supported(q[:, 0], cache["kp"], cap=cap):
+
+        def view(key, *entry):
+            pool = cache[key] if layer is None else cache[key][layer]
+            return pool.reshape(-1, page, *entry)
+        kp, vp = view("kp", J, N), view("vp", J, N)
+        if pa_ops.supported(q[:, 0], kp, cap=cap):
             if quant:
                 return pa_ops.paged_attention_quant(
-                    q[:, 0], cache["kp"], cache["vp"],
-                    cache["ksc"], cache["vsc"], table, lengths)[:, None]
+                    q[:, 0], kp, vp, view("ksc", J), view("vsc", J),
+                    table, lengths)[:, None]
             return pa_ops.paged_attention(
-                q[:, 0], cache["kp"], cache["vp"], table, lengths)[:, None]
-    B, M = table.shape
-    page = cache["kp"].shape[1]
+                q[:, 0], kp, vp, table, lengths)[:, None]
     T = M * page
-    kg = cache["kp"][table].reshape(B, T, *cache["kp"].shape[2:])
-    vg = cache["vp"][table].reshape(B, T, *cache["vp"].shape[2:])
+
+    def gather(key, *entry):
+        return take_pages(key, cache[key], table, layer).reshape(
+            B, T, *entry)
+    kg, vg = gather("kp", J, N), gather("vp", J, N)
     if quant:
-        kg = kv_dequantize(kg, cache["ksc"][table].reshape(B, T, -1))
-        vg = kv_dequantize(vg, cache["vsc"][table].reshape(B, T, -1))
+        kg = kv_dequantize(kg, gather("ksc", J)).astype(q.dtype)
+        vg = kv_dequantize(vg, gather("vsc", J)).astype(q.dtype)
     t = jnp.arange(T, dtype=jnp.int32)[None, :]
     k_pos = jnp.where(t < lengths[:, None], t, -1)
     return attend(q, kg, vg, positions, k_pos, causal=True, cap=cap)

@@ -76,6 +76,7 @@ def apply_block(
     state: Optional[dict] = None,
     causal: bool = True,
     page_table: Optional[jax.Array] = None,   # (B, M) paged-KV block table
+    layer=None,                      # index into stacked page pools
     q_chunk: int = 0,
     kv_chunk: int = 0,
     use_kernel: bool = False,
@@ -91,7 +92,7 @@ def apply_block(
         cache = None if state is None else state["cache"]
         out, new_cache = attn_mod.self_attention(
             params["mixer"], h, positions, cfg, window=window, causal=causal,
-            cache=cache, page_table=page_table, q_chunk=q_chunk,
+            cache=cache, page_table=page_table, layer=layer, q_chunk=q_chunk,
             kv_chunk=kv_chunk, use_kernel=use_kernel)
         x = x + out
         if kind == MIX_ATTN_CROSS:

@@ -22,7 +22,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.model import MIX_ATTN, MIX_ATTN_CROSS, ModelConfig
+from repro.models import attention as attn_mod
 from repro.models import blocks as blk
+from repro.models.attention import put_pages, take_pages
 from repro.models.common import dtype_of, normal_init, rms_norm, init_rmsnorm, split_keys
 
 
@@ -251,7 +253,6 @@ def init_paged_decode_state(cfg: ModelConfig, num_pages: int,
                          "exact-prefill path)")
     dtype = dtype_of(cfg.dtype)
     reps, rem = _reps_rem(cfg)
-    from repro.models import attention as attn_mod
 
     def pool(lead=()):
         one = {"cache": attn_mod.init_paged_cache(cfg, num_pages, page_size,
@@ -269,16 +270,18 @@ def init_paged_decode_state(cfg: ModelConfig, num_pages: int,
     }
 
 
+def _map_pools(fn, pstate: Dict[str, Any], *rest) -> Dict[str, Any]:
+    """``fn(key, leaf, *rest_leaves)`` over every pool leaf."""
+    pools = {"slots": pstate["slots"], "tail": pstate["tail"]}
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a, *r: fn(path[-1].key, a, *r), pools, *rest)
+
+
 def read_page(pstate: Dict[str, Any], page) -> Dict[str, Any]:
     """Slice physical page ``page`` out of every layer's pool (the spill
     payload: fresh small buffers, safe to hand to the sidecar while the pool
-    itself keeps being donated through decode steps).  Stacked ("slots")
-    leaves carry the page axis at 1, unstacked ("tail") at 0."""
-    def take(axis):
-        return lambda a: jax.lax.dynamic_index_in_dim(a, page, axis,
-                                                      keepdims=False)
-    return {"slots": jax.tree.map(take(1), pstate["slots"]),
-            "tail": jax.tree.map(take(0), pstate["tail"])}
+    itself keeps being donated through decode steps)."""
+    return _map_pools(lambda k, a: take_pages(k, a, page), pstate)
 
 
 def read_pages(pstate: Dict[str, Any], pages) -> Dict[str, Any]:
@@ -287,23 +290,15 @@ def read_pages(pstate: Dict[str, Any], pages) -> Dict[str, Any]:
     leaf — element ``i`` of the result tree equals ``read_page(pstate,
     pages[i])``.  Lets the handoff exporter move all of a request's prompt
     pages to the host in a single transfer instead of one sync per page."""
-    def take(axis):
-        return lambda a: jnp.moveaxis(jnp.take(a, pages, axis=axis), axis, 0)
-    return {"slots": jax.tree.map(take(1), pstate["slots"]),
-            "tail": jax.tree.map(take(0), pstate["tail"])}
+    return _map_pools(
+        lambda k, a: jnp.moveaxis(take_pages(k, a, pages), -2, 0), pstate)
 
 
 def write_page(pstate: Dict[str, Any], page, blob: Dict[str, Any]
                ) -> Dict[str, Any]:
     """Fault a spilled page's content back into every layer's pool."""
-    def put(axis):
-        def f(dst, src):
-            return jax.lax.dynamic_update_index_in_dim(
-                dst, src.astype(dst.dtype), page, axis)
-        return f
-    return {"slots": jax.tree.map(put(1), pstate["slots"], blob["slots"]),
-            "tail": jax.tree.map(put(0), pstate["tail"], blob["tail"]),
-            "pos": pstate["pos"]}
+    return dict(_map_pools(lambda k, a, b: put_pages(k, a, page, b),
+                           pstate, blob), pos=pstate["pos"])
 
 
 def load_prefix_pages(solo: Dict[str, Any], pstate: Dict[str, Any],
@@ -314,43 +309,30 @@ def load_prefix_pages(solo: Dict[str, Any], pstate: Dict[str, Any],
     at the scratch page, so the gathered garbage is masked off by ``pos``.
     Quantized pools dequantize on the way out (the dense solo cache is the
     model dtype; requantization on scatter-back is the only lossy step)."""
-    from repro.models import attention as attn_mod
+    def seed(dense_leaf, pool_cache, key, skey):
+        # dense (..., 1, C, J, N) <- the row's pages of every layer
+        gathered = take_pages(key, pool_cache[key], table_row).reshape(
+            dense_leaf.shape)
+        if skey in pool_cache:
+            scales = take_pages(skey, pool_cache[skey], table_row).reshape(
+                dense_leaf.shape[:-1])
+            gathered = attn_mod.kv_dequantize(gathered, scales)
+        return gathered.astype(dense_leaf.dtype)
 
-    def seed(pool_axis):
-        def f(dense_leaf, pool_cache, key, skey):
-            # dense (..., 1, C, J, N) <- pool (..., P, page, J, N)[table_row]
-            gathered = jnp.take(pool_cache[key], table_row, axis=pool_axis)
-            if skey in pool_cache:
-                scales = jnp.take(pool_cache[skey], table_row, axis=pool_axis)
-                gathered = attn_mod.kv_dequantize(gathered, scales)
-            return gathered.reshape(dense_leaf.shape).astype(dense_leaf.dtype)
-        return f
-
-    def fix_pos(cache_state):
-        C = cache_state["cache"]["pos"].shape[-1]
+    def fill(solo_state, pool_state):
+        cache = solo_state["cache"]
+        C = cache["pos"].shape[-1]
         t = jnp.arange(C, dtype=jnp.int32)
         pos = jnp.where(t < hit_len, t, -1)
-        cache_state["cache"]["pos"] = jnp.broadcast_to(
-            pos, cache_state["cache"]["pos"].shape)
-        return cache_state
+        return {"cache": {
+            "k": seed(cache["k"], pool_state["cache"], "kp", "ksc"),
+            "v": seed(cache["v"], pool_state["cache"], "vp", "vsc"),
+            "pos": jnp.broadcast_to(pos, cache["pos"].shape)}}
 
     out = dict(solo)
-    out["slots"] = {
-        i: fix_pos({"cache": {
-            "k": seed(1)(solo["slots"][i]["cache"]["k"],
-                         pstate["slots"][i]["cache"], "kp", "ksc"),
-            "v": seed(1)(solo["slots"][i]["cache"]["v"],
-                         pstate["slots"][i]["cache"], "vp", "vsc"),
-            "pos": solo["slots"][i]["cache"]["pos"]}})
-        for i in solo["slots"]}
-    out["tail"] = {
-        i: fix_pos({"cache": {
-            "k": seed(0)(solo["tail"][i]["cache"]["k"],
-                         pstate["tail"][i]["cache"], "kp", "ksc"),
-            "v": seed(0)(solo["tail"][i]["cache"]["v"],
-                         pstate["tail"][i]["cache"], "vp", "vsc"),
-            "pos": solo["tail"][i]["cache"]["pos"]}})
-        for i in solo["tail"]}
+    for group in ("slots", "tail"):
+        out[group] = {i: fill(solo[group][i], pstate[group][i])
+                      for i in solo[group]}
     out["pos"] = jnp.asarray(hit_len, jnp.int32)
     return out
 
@@ -360,47 +342,31 @@ def scatter_solo_pages(pstate: Dict[str, Any], solo: Dict[str, Any],
     """Admission's device half: scatter a prefilled solo dense cache into the
     pools at the pages ``assign`` maps (logical -> physical; scratch page 0
     for logical pages that were prefix hits or past the allocation, so shared
-    pages are never rewritten).  Quantized pools quantize on the way in,
-    scattering values and the matching scale rows under the same indices."""
-    from repro.models import attention as attn_mod
+    pages are never rewritten).  One scatter of page windows per leaf into
+    the (donated) pool, which it updates in place.  Quantized pools
+    quantize on the way in, scattering values and the matching scales
+    under the same indices."""
+    def scat(pool_cache, dense_leaf, key, skey):
+        # dense (..., 1, M*page, J, N) -> pages (..., M, page*J*N)
+        lead = dense_leaf.shape[:-4]                     # (reps,) or ()
+        M = assign.shape[0]
 
-    def scat(pool_axis):
-        def f(pool_cache, dense_leaf, key, skey):
-            pool_leaf = pool_cache[key]
-            page = pool_leaf.shape[pool_axis + 1]
-            M = assign.shape[0]
-            lead = dense_leaf.shape[:pool_axis]          # (reps,) or ()
-            paged = dense_leaf.reshape(
-                lead + (M, page) + dense_leaf.shape[pool_axis + 2:])
-            written = {}
-            if skey in pool_cache:
-                paged, scales = attn_mod.kv_quantize(paged)
-                written[skey] = (
-                    pool_cache[skey].at[:, assign].set(scales)
-                    if pool_axis == 1 else
-                    pool_cache[skey].at[assign].set(scales))
-            written[key] = (
-                pool_leaf.at[:, assign].set(paged.astype(pool_leaf.dtype))
-                if pool_axis == 1 else
-                pool_leaf.at[assign].set(paged.astype(pool_leaf.dtype)))
-            return written
-        return f
+        def put(k, new):
+            return put_pages(k, pool_cache[k], assign,
+                             new.reshape(lead + (M, -1)))
+        if skey not in pool_cache:
+            return {key: put(key, dense_leaf)}
+        values, scales = attn_mod.kv_quantize(dense_leaf)
+        return {key: put(key, values), skey: put(skey, scales)}
 
     out = {"slots": {}, "tail": {}, "pos": pstate["pos"]}
-    for i in pstate["slots"]:
-        cache = {}
-        cache.update(scat(1)(pstate["slots"][i]["cache"],
-                             solo["slots"][i]["cache"]["k"], "kp", "ksc"))
-        cache.update(scat(1)(pstate["slots"][i]["cache"],
-                             solo["slots"][i]["cache"]["v"], "vp", "vsc"))
-        out["slots"][i] = {"cache": cache}
-    for i in pstate["tail"]:
-        cache = {}
-        cache.update(scat(0)(pstate["tail"][i]["cache"],
-                             solo["tail"][i]["cache"]["k"], "kp", "ksc"))
-        cache.update(scat(0)(pstate["tail"][i]["cache"],
-                             solo["tail"][i]["cache"]["v"], "vp", "vsc"))
-        out["tail"][i] = {"cache": cache}
+    for group in ("slots", "tail"):
+        for i in pstate[group]:
+            pool = pstate[group][i]["cache"]
+            dense = solo[group][i]["cache"]
+            out[group][i] = {"cache": {
+                **scat(pool, dense["k"], "kp", "ksc"),
+                **scat(pool, dense["v"], "vp", "vsc")}}
     return out
 
 
@@ -445,16 +411,42 @@ def _run_stack(
     qc, kc = policy.chunks_for(x.shape[1])
     aux0 = jnp.zeros((), jnp.float32)
 
-    def apply_one(p, kind, x, st):
+    def apply_one(p, kind, x, st, layer=None):
         return blk.apply_block(
             p, kind, x, positions, cfg, memory=memory, state=st,
-            causal=causal, page_table=page_table, q_chunk=qc, kv_chunk=kc,
-            use_kernel=policy.use_kernel,
+            causal=causal, page_table=page_table, layer=layer, q_chunk=qc,
+            kv_chunk=kc, use_kernel=policy.use_kernel,
             constrain_recurrence=policy.constrain_recurrence)
 
     new_states: Optional[dict] = {"slots": {}, "tail": {}} if states is not None else None
 
-    if reps:
+    if reps and page_table is not None:
+        # Paged decode/verify: the stacked page pools ride in the carry and
+        # each layer writes its new entries into them in place, by layer
+        # index.  Scanned as xs/ys instead, every layer's whole pool would be
+        # sliced out, copied and restacked in every step.
+        def paged_body(carry, xs):
+            x, aux, pools = carry
+            p_slice, r = xs
+            pools = dict(pools)
+            for i, kind in enumerate(pattern):
+                x, pools[str(i)], a = apply_one(
+                    p_slice[str(i)], kind, x, pools[str(i)], layer=r)
+                aux = aux + a
+            return (x, aux, pools), None
+
+        carry = (x, aux0, states["slots"])
+        if policy.scan_layers:
+            carry, _ = jax.lax.scan(
+                paged_body, carry,
+                (layer_params, jnp.arange(reps, dtype=jnp.int32)))
+        else:
+            for r in range(reps):
+                carry, _ = paged_body(
+                    carry, (jax.tree.map(lambda a, r=r: a[r], layer_params),
+                            r))
+        x, aux, new_states["slots"] = carry
+    elif reps:
         slot_states = states["slots"] if states is not None else None
 
         def body(carry, xs):

@@ -213,13 +213,17 @@ class PagedKVBackend(CacheBackend):
         if scfg.max_seq_len % scfg.page_size:
             raise ValueError(f"max_seq_len ({scfg.max_seq_len}) must be a "
                              f"multiple of page_size ({scfg.page_size})")
-        from repro.models.attention import KV_QUANT_MODES
+        from repro.models.attention import KV_QUANT_MODES, POOL_PAGE_MULTIPLE
         if scfg.kv_quant not in KV_QUANT_MODES:
             raise ValueError(f"kv_quant={scfg.kv_quant!r}: expected one of "
                              f"{KV_QUANT_MODES}")
         self.page_size = scfg.page_size
         self.pages_per_seq = scfg.max_seq_len // scfg.page_size
-        num_pages = scfg.num_pages or (scfg.max_batch * self.pages_per_seq + 1)
+        # Default: full residency for every slot plus the scratch page,
+        # rounded up to the pool's tile-aligned size.
+        m = POOL_PAGE_MULTIPLE
+        num_pages = scfg.num_pages or \
+            -(-(scfg.max_batch * self.pages_per_seq + 1) // m) * m
         if num_pages < self.pages_per_seq + 1:
             raise ValueError(
                 f"num_pages ({num_pages}) must cover one full sequence "
