@@ -31,7 +31,7 @@ from bench.weights import Shapes
 CACHE_DIR = ROOT / ".jax_cache"
 DRAIN_S = 60.0            # longest wait for a window request's first token
 FINISH_S = 60.0           # longest wait for requests to finish for the check
-TRACE_S = 4.0             # traced part of the window, from its start
+TRACE_S = 4.0             # traced part of the window
 
 
 class NoChip(RuntimeError):
@@ -257,7 +257,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     setup_s = time.perf_counter() - t_process
     log(f"setup: {json.dumps(phases)} setup_s={setup_s:.3f}")
 
-    loop = _Loop(engine, items, trace)
+    loop = _Loop(engine, items, trace, backlog)
     if backlog:
         loop.fill()
     else:
@@ -397,12 +397,13 @@ def _check(run: Run, seed: int, s: Shapes, limits: Dict[str, Any],
 class _Loop:
     """Offers the traffic to the engine and records what comes back."""
 
-    def __init__(self, engine, items, trace: bool):
+    def __init__(self, engine, items, trace: bool, backlog: bool = False):
         self.engine = engine
         self.items = items
         self.next = 0
         self.reqs: List[Req] = []
         self.trace = trace
+        self.backlog = backlog
         self.base = 0.0
         self.win0 = self.win1 = 0.0
         self.trace_t0 = self.trace_t1 = self.t_end = 0.0
@@ -464,6 +465,11 @@ class _Loop:
         self.stats0 = self.engine.stats()
         offer = self.next < len(self.items)
         if self.trace:
+            if self.backlog:
+                # A backlog's window opens with every slot mid-decode: the
+                # trace starts once a request finishes, so that it holds the
+                # admission that takes the freed slot.
+                self._run_until_a_request_ends(self.win1 - TRACE_S)
             self._logdir = tempfile.mkdtemp(prefix="bench-trace-")
             jax.profiler.start_trace(self._logdir)
             self.trace_t0 = time.perf_counter()
@@ -475,6 +481,12 @@ class _Loop:
         self._run_until(self.win1, offer)
         self.stats1 = self.engine.stats()
         self.t_end = time.perf_counter()
+
+    def _run_until_a_request_ends(self, until: float) -> None:
+        ended = sum(r.done for r in self.reqs)
+        while (time.perf_counter() < until
+               and sum(r.done for r in self.reqs) == ended):
+            self._step()
 
     def drain(self, limit_s: float) -> None:
         """Step on, sending nothing more, until every request due in the

@@ -4,8 +4,13 @@
 
 One process, one engine set-up per rate (programs stay compiled in the
 process).  For each rate it prints the offered load and the latencies, so
-the highest rate with no growing queue can be read off; the cell's traffic
-file then fixes its rate at about four fifths of that.
+the highest rate with no growing queue (the knee) can be read off.  A cell
+judged by its tails offers about four fifths of the knee; one judged by the
+tokens per second served offers twice the knee, so that its queue grows
+through the window and the program, not the offered load, sets the rate.
+Besides the cell's
+end-to-end metrics it prints every per-layer metric that reads without a
+trace (the latency tails, tokens per step, prefix hits).
 """
 from __future__ import annotations
 
@@ -38,6 +43,7 @@ def main(argv=None) -> int:
     for rate in args.rates:
         cell = copy.deepcopy(base)
         cell.traffic["arrival"]["rate_per_s"] = rate
+        cell.e2e = base.e2e + base.layer
         out = harness.run_cell(cell, args.seed, args.seconds, False,
                                time.perf_counter(), check=False)
         print(json.dumps({"rate_per_s": rate, "attempted": out["attempted"],

@@ -17,9 +17,7 @@ import pytest
 
 from bench import harness
 from bench.spec import load_limits
-from bench.tests.conftest import tiny_cell
-
-CELLS = ["smollm-360m.chat-prefix", "mistral-7b-v0.3-16l.docs-backlog"]
+from bench.tests.conftest import CELLS, tiny_cell
 
 
 def _stale_state(real):

@@ -34,7 +34,7 @@ def test_blocked_share_reads_nothing_without_the_counter(stats):
 
 
 def test_traced_run_reports_blocked_share():
-    name = "smollm-360m.chat-prefix"
+    name = "smollm-360m.chat-prefix-sat"
     cell = tiny_cell(name)
     out = harness.run_cell(cell, 2**32 + 11, 3.0, True, time.perf_counter(),
                            require_tpu=False, limits=load_limits(name))

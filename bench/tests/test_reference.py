@@ -11,15 +11,15 @@ import pytest
 
 from bench.model import make_params, model_config, serve_config
 from bench.reference import decoder
-from bench.tests.conftest import tiny_cell
+from bench.tests.conftest import CELLS, tiny_cell
 from bench.weights import Shapes
 
 SEED = 2**31 + 77
 
 
-@pytest.fixture(scope="module")
-def f32_model():
-    cell = tiny_cell("smollm-360m.chat-prefix", dtype="float32")
+@pytest.fixture(scope="module", params=CELLS)
+def f32_model(request):
+    cell = tiny_cell(request.param, dtype="float32")
     s = Shapes.from_config(cell.config)
     cfg = model_config("tiny", cell.config)
     return cell, s, cfg, make_params(SEED, s)
@@ -68,8 +68,7 @@ def test_control_departs_from_reference(f32_model):
     assert 1e-3 < err < 0.5
 
 
-@pytest.mark.parametrize("name", ["smollm-360m.chat-prefix",
-                                  "mistral-7b-v0.3-16l.docs-backlog"])
+@pytest.mark.parametrize("name", CELLS)
 def test_params_have_the_programs_layout(name):
     """The seeded weights fill exactly the tree the program's own
     ``init_params`` makes, leaf for leaf, at the configuration's sizes."""

@@ -11,8 +11,9 @@ import pytest
 from bench import spec
 from bench.spec import ROOT
 
-CMD = [sys.executable, "bench/run.py", "--workload", "smollm-360m.chat-prefix",
-       "--seed", "3", "--seconds", "1", "--trace", "0"]
+CMD = [sys.executable, "bench/run.py", "--workload",
+       "smollm-360m.chat-prefix-sat", "--seed", "3", "--seconds", "1",
+       "--trace", "0"]
 
 
 def _run(cwd):
@@ -58,11 +59,11 @@ def test_new_metric_is_found_by_name(tmp_path, reader):
     bench["per_layer"].append({
         "name": "throwaway.chat", "unit": "n", "better": "higher",
         "source": "program_counter", "layer": "device", "moves": "itl_p95_ms",
-        "workloads": ["smollm-360m.chat-prefix"]})
+        "workloads": ["smollm-360m.chat-prefix-sat"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     (root / "bench" / "metrics" / f"{reader}.py").write_text(
         "def read(run):\n    return 42.0 if run else None\n")
-    cell = spec.load_cell("smollm-360m.chat-prefix", root)
+    cell = spec.load_cell("smollm-360m.chat-prefix-sat", root)
     assert "throwaway.chat" in [m.name for m in cell.layer]
     other = spec.load_cell("other.cell", root)
     assert "throwaway.chat" not in [m.name for m in other.layer]

@@ -18,6 +18,7 @@ def mix(name):
 
 
 @pytest.mark.parametrize("name,horizon", [("chat-prefix", 30.0),
+                                          ("chat-prefix-sat", 30.0),
                                           ("docs-backlog", 0.0)])
 def test_same_seed_same_inputs(name, horizon):
     a = traffic.generate(mix(name), 7, horizon, 1000)
@@ -30,6 +31,7 @@ def test_same_seed_same_inputs(name, horizon):
 
 
 @pytest.mark.parametrize("name,horizon", [("chat-prefix", 30.0),
+                                          ("chat-prefix-sat", 30.0),
                                           ("docs-backlog", 0.0)])
 def test_seeds_differ_in_order_not_in_load(name, horizon):
     big = 2**31 + 12345
@@ -86,11 +88,21 @@ def test_sessions_share_system_prompt_and_history():
     assert multi > 10
 
 
-def test_open_loop_rate():
-    m = mix("chat-prefix")
+@pytest.mark.parametrize("name", ["chat-prefix", "chat-prefix-sat"])
+def test_open_loop_rate(name):
+    m = mix(name)
     items = traffic.generate(m, 5, 200.0, 1000)
     starts = {}
     for it in items:
         starts.setdefault(it.session, it.due_s)
     rate = len(starts) / 200.0
     assert rate == pytest.approx(m["arrival"]["rate_per_s"], rel=0.15)
+
+
+def test_saturated_mix_is_the_chat_mix_at_a_higher_rate():
+    """The chat mix above its knee differs from the one kept for the tails
+    cell in its arrival rate alone."""
+    base, sat = mix("chat-prefix"), mix("chat-prefix-sat")
+    assert sat["arrival"]["rate_per_s"] > base["arrival"]["rate_per_s"]
+    sat["arrival"]["rate_per_s"] = base["arrival"]["rate_per_s"]
+    assert sat == base
