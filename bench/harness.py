@@ -3,7 +3,9 @@
 The process holds one chip.  It makes the weights from the seed on the
 device, builds the engine through ``repro.serve.make_engine``, warms every
 program shape the cell's traffic uses, offers the traffic for the window,
-then checks what the window served against ``bench/reference``.
+then checks what the window served against ``bench/reference``.  What
+depends on the model's equations (sizes, weights, the reference, the work
+counts) comes from the cell's architecture file in ``bench/arch/``.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -22,11 +25,9 @@ import numpy as np
 
 from bench import traffic as traffic_mod
 from bench import trace as trace_mod
-from bench.model import make_params, model_config, serve_config
 from bench.peaks import peaks
-from bench.reference.decoder import served_gaps
 from bench.spec import ROOT, Cell, metric_reader
-from bench.weights import Shapes
+from repro.config.run import ServeConfig
 
 CACHE_DIR = ROOT / ".jax_cache"
 DRAIN_S = 60.0            # longest wait for a window request's first token
@@ -62,7 +63,8 @@ class Req:
 class Run:
     """What the metric readers see."""
     cell: Cell
-    shapes: Shapes
+    shapes: Any                      # the architecture's sizes
+    arch: ModuleType                 # the cell's bench/arch/ file
     chips: int
     peaks: Dict[str, float]
     reqs: List[Req]
@@ -126,6 +128,15 @@ class CompileCounter:
 
     def snapshot(self) -> Dict[str, int]:
         return {"built": self.built, "hits": self.hits, "misses": self.misses}
+
+
+def serve_config(c: Dict[str, Any], **over) -> ServeConfig:
+    """The program's ``ServeConfig`` from the configuration's ``serve``
+    group, greedy and with no end-of-sequence token, updated by ``over``."""
+    kw = dict(c["serve"], temperature=0.0, eos_id=-1)
+    kw["prefill_buckets"] = tuple(kw["prefill_buckets"])
+    kw.update(over)
+    return ServeConfig(**kw)
 
 
 def enable_cache() -> None:
@@ -208,13 +219,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     enable_cache()
     phases: Dict[str, Any] = {"import_s": time.perf_counter() - t_process}
 
-    s = Shapes.from_config(cell.config)
-    cfg = model_config(cell.config_name, cell.config)
+    arch = cell.arch
+    s = arch.shapes(cell.config)
+    cfg = arch.model_config(cell.config_name, cell.config)
     scfg = serve_config(cell.config, **cell.traffic.get("serve", {}))
 
     t = time.perf_counter()
     c0 = counter.snapshot()
-    params = make_params(seed, s)
+    params = arch.make_params(seed, s)
     jax.block_until_ready(params)
     phases["init_s"] = time.perf_counter() - t
     phases["init_programs"] = _delta(counter.snapshot(), c0)
@@ -287,7 +299,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     summary = None
     if trace:
         summary = loop.read_trace(cell.chips)
-    run = Run(cell, s, cell.chips, peaks(dev.device_kind) if require_tpu
+    run = Run(cell, s, arch, cell.chips, peaks(dev.device_kind) if require_tpu
               else peaks("TPU v5 lite"), loop.reqs, loop.win0, loop.win1,
               loop.stats0, loop.stats1, summary, loop.trace_t0, loop.trace_t1,
               loop.t_end)
@@ -299,8 +311,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     del engine, params, loop
     gc.collect()
 
-    checks, readings, n_checked = (_check(run, seed, s, limits or {},
-                                          control)
+    checks, readings, n_checked = (_check(run, seed, limits or {}, control)
                                    if check else ({}, {}, 0))
     correct = n_checked > 0 and all(v <= lim for v, lim in checks.values())
 
@@ -371,7 +382,7 @@ def _plan(limits: Dict[str, Any]) -> Dict[str, int]:
     return limits.get("sample", {"min_tokens": 256, "max_requests": 6})
 
 
-def _check(run: Run, seed: int, s: Shapes, limits: Dict[str, Any],
+def _check(run: Run, seed: int, limits: Dict[str, Any],
            control: bool = False):
     """The numbers compared, each (value, limit); other readings; and how
     many served tokens were compared.  With ``control``, the control's
@@ -384,12 +395,12 @@ def _check(run: Run, seed: int, s: Shapes, limits: Dict[str, Any],
         return {"widest_logit_gap": (float("inf"), lim)}, {}, 0
     t = time.perf_counter()
     reqs = [(r.item.prompt, r.tokens) for r in picked]
-    gaps = served_gaps(seed, s, reqs)
+    gaps = run.arch.served_gaps(seed, run.shapes, reqs)
     log(f"reference: {len(picked)} requests, {len(gaps)} served tokens, "
         f"{time.perf_counter() - t:.2f} s")
     if not control:
         return {"widest_logit_gap": (float(gaps.max()), lim)}, {}, len(gaps)
-    ctl = served_gaps(seed, s, reqs, control=True)
+    ctl = run.arch.served_gaps(seed, run.shapes, reqs, control=True)
     return ({"widest_logit_gap": (float(ctl.max()), lim)},
             {"program_widest_logit_gap": float(gaps.max())}, len(ctl))
 

@@ -1,7 +1,11 @@
 """Plain decoder-only transformer in float32: the reference for ``correct``.
 
-The equations of a Llama/Mistral block, written from the published
-description and nothing of the program:
+Shared by every architecture: rms norm, rotary embeddings, blocked causal
+attention and ``logits_at``, which runs the layer function that an
+architecture's file in ``bench/arch/`` passes (another block's equations go
+in ``bench/reference/<name>.py``).  The Llama/Mistral block
+(``dense_block``), written from the published description and nothing of
+the program:
 
     h   = x + Wo . attn(RoPE(Wq . rms(x)), RoPE(Wk . rms(x)), Wv . rms(x))
     out = h + Wdown . (silu(Wgate . rms(h)) * Wup . rms(h))
@@ -21,13 +25,13 @@ a later change might be tempted to serve in.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import Shapes, global_weights, layer_weights, seed_key, served
+from bench.weights import seed_key, served
 
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_BLOCK = 256          # query rows per attention block
@@ -40,17 +44,17 @@ def _fp8(x: jax.Array) -> jax.Array:
     return q * scale
 
 
-def _mm(spec: str, a, b, fp8: bool):
+def mm(spec: str, a, b, fp8: bool):
     if fp8:
         a, b = _fp8(a), _fp8(b)
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     # x (N, T, H, n); pos (T,)
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
@@ -60,7 +64,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v, fp8: bool):
+def attention(q, k, v, fp8: bool):
     # q (N, T, H, n), k/v (N, T, J, n); causal over T, in query blocks.
     N, T, H, n = q.shape
     J = k.shape[2]
@@ -70,73 +74,82 @@ def _attention(q, k, v, fp8: bool):
 
     def block(i):
         qb = jax.lax.dynamic_slice_in_dim(q, i * Q_BLOCK, Q_BLOCK, axis=1)
-        s = _mm("nsjgd,ntjd->njgst", qb, k, fp8)
+        s = mm("nsjgd,ntjd->njgst", qb, k, fp8)
         qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
         s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return _mm("njgst,ntjd->nsjgd", p, v, fp8)
+        return mm("njgst,ntjd->nsjgd", p, v, fp8)
 
     out = jax.lax.map(block, jnp.arange(T // Q_BLOCK))     # (nb, N, Q, J, G, n)
     return jnp.moveaxis(out, 0, 1).reshape(N, T, H * n)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "fp8"))
-def _layer(x, key, layer, s: Shapes, fp8: bool):
-    w = served(layer_weights(key, s, layer), s)
+def dense_block(x, w, s, fp8: bool):
+    """One Llama/Mistral block on activations ``x`` (N, T, d) with its
+    float32 weights ``w`` (``layer_weights`` of ``bench/arch/dense.py``)."""
     N, T, _ = x.shape
     n = s.head_dim
     pos = jnp.arange(T)
-    h = _rms(x, w["attn_norm"], s.eps)
-    q = _mm("ntd,de->nte", h, w["wq"], fp8).reshape(N, T, s.heads, n)
-    k = _mm("ntd,de->nte", h, w["wk"], fp8).reshape(N, T, s.kv_heads, n)
-    v = _mm("ntd,de->nte", h, w["wv"], fp8).reshape(N, T, s.kv_heads, n)
-    q, k = _rope(q, pos, s.rope_theta), _rope(k, pos, s.rope_theta)
-    x = x + _mm("nte,ed->ntd", _attention(q, k, v, fp8), w["wo"], fp8)
-    h = _rms(x, w["mlp_norm"], s.eps)
-    g = _mm("ntd,df->ntf", h, w["w_gate"], fp8)
-    u = _mm("ntd,df->ntf", h, w["w_up"], fp8)
-    return x + _mm("ntf,fd->ntd", jax.nn.silu(g) * u, w["w_down"], fp8)
+    h = rms(x, w["attn_norm"], s.eps)
+    q = mm("ntd,de->nte", h, w["wq"], fp8).reshape(N, T, s.heads, n)
+    k = mm("ntd,de->nte", h, w["wk"], fp8).reshape(N, T, s.kv_heads, n)
+    v = mm("ntd,de->nte", h, w["wv"], fp8).reshape(N, T, s.kv_heads, n)
+    q, k = rope(q, pos, s.rope_theta), rope(k, pos, s.rope_theta)
+    x = x + mm("nte,ed->ntd", attention(q, k, v, fp8), w["wo"], fp8)
+    h = rms(x, w["mlp_norm"], s.eps)
+    g = mm("ntd,df->ntf", h, w["w_gate"], fp8)
+    u = mm("ntd,df->ntf", h, w["w_up"], fp8)
+    return x + mm("ntf,fd->ntd", jax.nn.silu(g) * u, w["w_down"], fp8)
 
 
-@functools.partial(jax.jit, static_argnames=("s",))
-def _embed(tokens, key, s: Shapes):
+@functools.partial(jax.jit, static_argnames=("global_weights", "s"))
+def _embed(tokens, key, global_weights, s):
     return served(global_weights(key, s), s)["embed"][tokens]
 
 
-@functools.partial(jax.jit, static_argnames=("s", "fp8"))
-def _logits(x, rows, key, s: Shapes, fp8: bool):
+@functools.partial(jax.jit, static_argnames=("global_weights", "s", "fp8"))
+def _logits(x, rows, key, global_weights, s, fp8: bool):
     g = served(global_weights(key, s), s)
     head = g["embed"].T if s.tied else g["unembed"]
-    h = _rms(x, g["final_norm"], s.eps)
+    h = rms(x, g["final_norm"], s.eps)
     picked = h[rows[:, 0], rows[:, 1]]                      # (R, d)
-    return _mm("rd,dv->rv", picked, head, fp8)
+    return mm("rd,dv->rv", picked, head, fp8)
 
 
-def logits_at(seed: int, s: Shapes, seqs: Sequence[np.ndarray],
-              wanted: Sequence[Sequence[int]], fp8: bool = False) -> np.ndarray:
+def logits_at(seed: int, s, seqs: Sequence[np.ndarray],
+              wanted: Sequence[Sequence[int]], layer: Callable,
+              global_weights: Callable, fp8: bool = False) -> np.ndarray:
     """Logits (R, vocab) at ``wanted[i]`` positions of each sequence ``i``,
-    rows in order.  Position ``p``'s logits predict token ``p + 1``."""
+    rows in order.  Position ``p``'s logits predict token ``p + 1``.
+
+    The architecture gives ``layer(x, key, i, s, fp8)``, its layer ``i``
+    (a traced int32) on activations ``x`` (N, T, d) with weights made from
+    ``key``, and ``global_weights(key, s)``: float32 ``embed`` (vocab, d),
+    ``final_norm`` and, unless ``s.tied``, ``unembed`` (d, vocab).  The
+    shapes ``s`` also give ``layers``, ``eps`` and the served ``dtype``."""
     key = seed_key(seed)
     T = max(len(t) for t in seqs)
     T = -(-T // PAD) * PAD
     toks = np.zeros((len(seqs), T), np.int32)
     for i, t in enumerate(seqs):
         toks[i, :len(t)] = t
-    x = _embed(jnp.asarray(toks), key, s)
-    for layer in range(s.layers):
-        x = _layer(x, key, jnp.asarray(layer, jnp.int32), s, fp8)
+    x = _embed(jnp.asarray(toks), key, global_weights, s)
+    for i in range(s.layers):
+        x = layer(x, key, jnp.asarray(i, jnp.int32), s, fp8)
     rows = np.array([(i, p) for i, ps in enumerate(wanted) for p in ps],
                     np.int32).reshape(-1, 2)
-    return np.asarray(_logits(x, jnp.asarray(rows), key, s, fp8))
+    return np.asarray(_logits(x, jnp.asarray(rows), key, global_weights, s,
+                              fp8))
 
 
-def served_gaps(seed: int, s: Shapes,
-                requests: Sequence[Tuple[np.ndarray, List[int]]],
+def served_gaps(seed: int, s, requests: Sequence[Tuple[np.ndarray, List[int]]],
+                layer: Callable, global_weights: Callable,
                 control: bool = False) -> np.ndarray:
     """For each served token of each ``(prompt, served tokens)`` request: how
     far the reference's logit of that token lies below the reference's best.
     With ``control``, the tokens are instead those the fp8 computation puts
-    first at the same positions."""
+    first at the same positions.  ``layer`` and ``global_weights`` are the
+    architecture's, as ``logits_at`` takes them."""
     seqs, wanted, served_toks = [], [], []
     for prompt, out in requests:
         L = len(prompt)
@@ -144,9 +157,10 @@ def served_gaps(seed: int, s: Shapes,
                                     np.asarray(out[:-1], np.int32)]))
         wanted.append(list(range(L - 1, L - 1 + len(out))))
         served_toks.extend(int(t) for t in out)
-    ref = logits_at(seed, s, seqs, wanted)
+    ref = logits_at(seed, s, seqs, wanted, layer, global_weights)
     if control:
-        picked = logits_at(seed, s, seqs, wanted, fp8=True).argmax(-1)
+        picked = logits_at(seed, s, seqs, wanted, layer, global_weights,
+                           fp8=True).argmax(-1)
     else:
         picked = np.asarray(served_toks)
     return ref.max(-1) - ref[np.arange(len(picked)), picked]

@@ -20,6 +20,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import copy  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -41,9 +42,10 @@ def main(argv=None) -> int:
 
     base = load_cell(args.workload)
     for rate in args.rates:
-        cell = copy.deepcopy(base)
-        cell.traffic["arrival"]["rate_per_s"] = rate
-        cell.e2e = base.e2e + base.layer
+        traffic = copy.deepcopy(base.traffic)
+        traffic["arrival"]["rate_per_s"] = rate
+        cell = dataclasses.replace(base, traffic=traffic,
+                                   e2e=base.e2e + base.layer)
         out = harness.run_cell(cell, args.seed, args.seconds, False,
                                time.perf_counter(), check=False)
         print(json.dumps({"rate_per_s": rate, "attempted": out["attempted"],

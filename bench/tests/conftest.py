@@ -26,7 +26,7 @@ CELLS = _cells()
 def any_cell(name: str):
     """The workload ``name`` from BENCHMARK.json, or a kept one."""
     import json
-    from bench.spec import BENCH, Cell, load_cell
+    from bench.spec import BENCH, Cell, architecture, load_cell
     try:
         return load_cell(name)
     except KeyError:
@@ -35,7 +35,8 @@ def any_cell(name: str):
             config = json.load(f)
         with open(BENCH / "traffic" / f"{mix}.json") as f:
             traffic = json.load(f)
-        return Cell(name, conf, mix, 1, config, traffic, [], [])
+        return Cell(name, conf, mix, 1, config,
+                    architecture(config["model_type"]), traffic, [], [])
 
 
 def tiny_cell(name: str, dtype: str = "bfloat16", widths: bool = False):

@@ -12,8 +12,8 @@ BLOCKED = "sidecar_blocked_share.chat"
 
 
 def _run(stats0, stats1, seconds=30.0):
-    return harness.Run(cell=None, shapes=None, chips=1, peaks={}, reqs=[],
-                       win0=100.0, win1=100.0 + seconds,
+    return harness.Run(cell=None, shapes=None, arch=None, chips=1, peaks={},
+                       reqs=[], win0=100.0, win1=100.0 + seconds,
                        stats0=stats0, stats1=stats1)
 
 

@@ -9,10 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.model import make_params, model_config, serve_config
+from bench.harness import serve_config
 from bench.reference import decoder
 from bench.tests.conftest import CELLS, tiny_cell
-from bench.weights import Shapes
 
 SEED = 2**31 + 77
 
@@ -20,19 +19,21 @@ SEED = 2**31 + 77
 @pytest.fixture(scope="module", params=CELLS)
 def f32_model(request):
     cell = tiny_cell(request.param, dtype="float32")
-    s = Shapes.from_config(cell.config)
-    cfg = model_config("tiny", cell.config)
-    return cell, s, cfg, make_params(SEED, s)
+    s = cell.arch.shapes(cell.config)
+    cfg = cell.arch.model_config("tiny", cell.config)
+    return cell, s, cfg, cell.arch.make_params(SEED, s)
 
 
 def test_forward_logits_match(f32_model):
     from repro.models.transformer import forward
-    _, s, cfg, params = f32_model
+    cell, s, cfg, params = f32_model
     toks = np.random.default_rng(0).integers(0, s.vocab, (2, 40))
     with jax.default_matmul_precision("highest"):
         prog, _, _ = forward(params, cfg, jnp.asarray(toks, jnp.int32))
     ref = decoder.logits_at(SEED, s, list(toks),
-                            [list(range(40)), list(range(40))])
+                            [list(range(40)), list(range(40))],
+                            cell.arch.reference_layer,
+                            cell.arch.global_weights)
     np.testing.assert_allclose(np.asarray(prog).reshape(-1, s.vocab), ref,
                                atol=2e-5, rtol=1e-5)
 
@@ -55,15 +56,18 @@ def test_served_tokens_are_reference_greedy(f32_model):
                        eng.request(rid).prefix_hit_tokens)
         eng.close()
     assert outs[2][2] > 0, "the shared system prompt was not reused"
-    gaps = decoder.served_gaps(SEED, s, [(p, t) for p, t, _ in outs.values()])
+    gaps = cell.arch.served_gaps(SEED, s,
+                                 [(p, t) for p, t, _ in outs.values()])
     assert gaps.max() < 1e-4
 
 
 def test_control_departs_from_reference(f32_model):
-    _, s, _, _ = f32_model
+    cell, s, _, _ = f32_model
     toks = np.random.default_rng(2).integers(0, s.vocab, 64)
-    ref = decoder.logits_at(SEED, s, [toks], [list(range(64))])
-    low = decoder.logits_at(SEED, s, [toks], [list(range(64))], fp8=True)
+    model = (cell.arch.reference_layer, cell.arch.global_weights)
+    ref = decoder.logits_at(SEED, s, [toks], [list(range(64))], *model)
+    low = decoder.logits_at(SEED, s, [toks], [list(range(64))], *model,
+                            fp8=True)
     err = np.abs(low - ref).max() / np.abs(ref).max()
     assert 1e-3 < err < 0.5
 
@@ -75,9 +79,9 @@ def test_params_have_the_programs_layout(name):
     from bench.tests.conftest import any_cell
     from repro.models.transformer import init_params
     cell = any_cell(name)
-    s = Shapes.from_config(cell.config)
-    cfg = model_config(name, cell.config)
-    ours = jax.eval_shape(lambda: make_params(SEED, s))
+    s = cell.arch.shapes(cell.config)
+    cfg = cell.arch.model_config(name, cell.config)
+    ours = jax.eval_shape(lambda: cell.arch.make_params(SEED, s))
     theirs = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):

@@ -1,17 +1,18 @@
-"""bench/work.py against values worked by hand for smollm-360m."""
+"""The dense architecture's work counts (bench/arch/dense.py) against
+values worked by hand for smollm-360m."""
 import json
 
 import pytest
 
-from bench import work
-from bench.spec import BENCH
-from bench.weights import Shapes
+from bench.spec import BENCH, architecture
+
+dense = architecture("llama")
 
 
 @pytest.fixture(scope="module")
 def smollm():
     with open(BENCH / "configs" / "smollm-360m.json") as f:
-        return Shapes.from_config(json.load(f))
+        return dense.shapes(json.load(f))
 
 
 def test_smollm_shapes(smollm):
@@ -28,7 +29,7 @@ def test_one_smollm_decode_step(smollm):
     # 32 active rows, each at a context of 1000 positions.
     rows, ctx = 32, 1000
     n = 32 * 9_832_320 + 960 * 49152           # 361_820_160
-    flops, nbytes = work.decode_work(smollm, 1, rows * ctx, rows)
+    flops, nbytes = dense.decode_work(smollm, 1, rows * ctx, rows)
     attn = 4 * 32 * 15 * 64 * (rows * ctx)      # scores + weighted sum
     assert flops == pytest.approx(2 * n * rows + attn)
     assert flops == pytest.approx(23_156_490_240 + 3_932_160_000)
@@ -39,8 +40,8 @@ def test_one_smollm_decode_step(smollm):
 
 def test_prefill_counts_causal_attention(smollm):
     # Positions 10..13 after 10 cached: contexts 11, 12, 13, 14.
-    n = work.dense_params(smollm)
+    n = dense.dense_params(smollm)
     want = 2 * n * 4 + 4 * 32 * 15 * 64 * (11 + 12 + 13 + 14)
-    assert work.prefill_flops(smollm, 10, 14) == pytest.approx(want)
-    assert work.token_flops(smollm, 11) == pytest.approx(
+    assert dense.prefill_flops(smollm, 10, 14) == pytest.approx(want)
+    assert dense.token_flops(smollm, 11) == pytest.approx(
         2 * n + 4 * 32 * 15 * 64 * 11)
